@@ -67,7 +67,8 @@ def _dgemm_step(impl_cfg: dict, m: int, n: int, k: int, spec: MonitorSpec):
     def step(a, b, state, mp):
         with scalpel.collecting(spec, mp, state) as col:
             with scalpel.function("dgemm"):
-                c = ops.matmul(a, b, impl_cfg["schedule"], **kw)
+                c = ops.matmul(a, b, impl_cfg["schedule"], interpret=True,
+                               **kw)
                 scalpel.probe(
                     out=c,
                     refills=jnp.float32(cost["VMEM_TILE_REFILLS"]),

@@ -47,9 +47,11 @@ def correctness_and_speed(fast: bool):
     b = jax.random.normal(jax.random.PRNGKey(1), (k, n), jnp.float32)
     want = np.asarray(ref.matmul(a, b))
     for sched in ops.SCHEDULES:
-        got = ops.matmul(a, b, sched, bm=128, bn=128, bk=128)
+        got = ops.matmul(a, b, sched, bm=128, bn=128, bk=128,
+                         interpret=True)
         err = float(np.max(np.abs(np.asarray(got) - want)))
-        t = bench(lambda: ops.matmul(a, b, sched, bm=128, bn=128, bk=128),
+        t = bench(lambda: ops.matmul(a, b, sched, bm=128, bn=128, bk=128,
+                         interpret=True),
                   iters=3 if fast else 5)
         rows.append({"kernel": f"gemm/{sched}", "shape": f"{m}^3",
                      "max_err": f"{err:.1e}",
@@ -61,10 +63,11 @@ def correctness_and_speed(fast: bool):
     v = jax.random.normal(jax.random.PRNGKey(4), (bq, s, h, d), jnp.float32)
     want = np.asarray(ref.attention(q, kk, v, causal=True))
     got = ops.flash_attention(q, kk, v, causal=True, block_q=128,
-                              block_kv=128)
+                              block_kv=128, interpret=True)
     err = float(np.max(np.abs(np.asarray(got) - want)))
     t = bench(lambda: ops.flash_attention(q, kk, v, causal=True,
-                                          block_q=128, block_kv=128),
+                                          block_q=128, block_kv=128,
+                                          interpret=True),
               iters=3 if fast else 5)
     rows.append({"kernel": "flash_attn", "shape": f"s{s} h{h} d{d}",
                  "max_err": f"{err:.1e}",
@@ -74,9 +77,9 @@ def correctness_and_speed(fast: bool):
     la = -jnp.abs(jax.random.normal(jax.random.PRNGKey(5), (B, S, D))) * 0.3
     bb = jax.random.normal(jax.random.PRNGKey(6), (B, S, D))
     want = np.asarray(ref.ssm_scan(None, la, bb))
-    got = ops.ssm_scan(la, bb, chunk=256, bd=64)
+    got = ops.ssm_scan(la, bb, chunk=256, bd=64, interpret=True)
     err = float(np.max(np.abs(np.asarray(got) - want)))
-    t = bench(lambda: ops.ssm_scan(la, bb, chunk=256, bd=64),
+    t = bench(lambda: ops.ssm_scan(la, bb, chunk=256, bd=64, interpret=True),
               iters=3 if fast else 5)
     rows.append({"kernel": "ssm_scan", "shape": f"B{B} S{S} D{D}",
                  "max_err": f"{err:.1e}",

@@ -728,7 +728,7 @@ def work(x):
 from jax.sharding import PartitionSpec as P
 
 mon = scalpel.Monitor(spec)
-mesh = jax.make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 with sharding_ctx(mesh):
     step = jax.jit(mon.shard_wrap(work, mesh, in_specs=P("data"),
                                   out_specs=P("data")))
@@ -772,6 +772,8 @@ def run_monitor_psum_check() -> list[dict]:
     import sys
 
     env = dict(os.environ)
+    # two simulated host devices: pinned to the CPU, off any accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in sys.path if p
@@ -1450,6 +1452,8 @@ def run_serve_shard_sweep(n_lanes: int = 8, max_new: int = 32,
     import sys
 
     env = dict(os.environ)
+    # two simulated host devices: pinned to the CPU, off any accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = os.pathsep.join(p for p in sys.path if p)
     proc = subprocess.run(

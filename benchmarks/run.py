@@ -43,7 +43,11 @@ def main() -> int:
     print("ScALPEL-JAX benchmark suite")
     print("=" * 72)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     from . import case_study, kernels_bench, overhead, roofline
+
+    enable_compile_cache()
 
     def run_overhead():
         _write_overhead_json(overhead.main(fast=fast))

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro import core as scalpel
 from repro.core.adaptive import AdaptiveConfig
 from repro.core.context import EventSpec, MonitorSpec, ScopeContext
+from repro.launch.compile_cache import enable_compile_cache
 from repro.testing.faults import FaultInjector, TensorFault
 
 EVENTS = ("ACT_RMS", "ACT_ZERO_FRAC", "NAN_COUNT", "INF_COUNT")
@@ -57,6 +58,7 @@ def build_spec() -> MonitorSpec:
 
 
 def main():
+    enable_compile_cache()
     spec = build_spec()
     runtime = scalpel.ScalpelRuntime(spec, hook_every=CADENCE,
                                      graceful_shutdown=True)

@@ -39,6 +39,7 @@ import time
 import numpy as np
 
 from repro.core import plan as plan_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.telemetry.aggregator import Aggregator
 from repro.telemetry.head import FleetHead
 from repro.telemetry.simhost import build_spec
@@ -55,12 +56,15 @@ LINGER_S = 8.0            # h0 waits this long for the downlink hint
 
 def _env():
     env = dict(os.environ)
+    # simulated hosts run on the CPU: never contend for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     return env
 
 
 def main():
+    enable_compile_cache()
     spec = build_spec()
     agg = Aggregator(("127.0.0.1", 0), node_id="root", reservoir_k=256,
                      seed=7).serve()

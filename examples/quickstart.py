@@ -15,10 +15,12 @@ import jax
 
 from repro import core as scalpel
 from repro.configs import model_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import Arch
 
 
 def main():
+    enable_compile_cache()
     # -- 1. the application: a small LM forward+loss ----------------------
     arch = Arch(model_config("qwen3_14b", smoke=True))
     params = arch.init(jax.random.PRNGKey(0))

@@ -24,11 +24,13 @@ import jax
 import numpy as np
 
 from repro.configs import model_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import Arch
 from repro.serve.engine import ContinuousEngine, Engine, ServeConfig
 
 
 def main(shards: int = 1):
+    enable_compile_cache()
     arch = Arch(model_config("mistral_nemo_12b", smoke=True))
     params = arch.init(jax.random.PRNGKey(0))
     # lane_shards must divide n_lanes: 3 lanes solo, 4 lanes over 2 shards

@@ -14,12 +14,14 @@ import argparse
 
 from repro.configs import model_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import Arch
 from repro.optim import OptConfig
 from repro.train.loop import TrainLoopConfig, fit
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm_125m")
     ap.add_argument("--steps", type=int, default=200)

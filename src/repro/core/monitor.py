@@ -638,8 +638,9 @@ class Monitor:
         stamp, ring.  Donation semantics as in ``jit``.
 
         The returned callable exposes the underlying ``jax.jit`` object as
-        ``._cjit`` (for cache-stats/no-retrace assertions and lowering/HLO
-        inspection: the donation checks the benchmarks record).
+        ``._cjit`` (for cache-stats/no-retrace assertions: the donation
+        checks the benchmarks record), and ``.lower(mstate, *args)`` — the
+        jit's ``lower`` with the wrapped signature, for HLO inspection.
         """
 
         def core(calls, values, samples, sched_calls, step, ring, params,
@@ -663,12 +664,14 @@ class Monitor:
             donate = (0, 1, 2) + sched + (4,) + donate
         cjit = jax.jit(core, donate_argnums=donate, **jit_kwargs)
 
+        def leaves(mstate: MonitorState):
+            return (mstate.calls, mstate.values, mstate.samples,
+                    mstate.sched_calls, mstate.step, mstate.ring,
+                    mstate.params, mstate.tparams)
+
         def stepped(mstate: MonitorState, *args):
             out, (calls, values, samples, sched_calls, step, ring) = cjit(
-                mstate.calls, mstate.values, mstate.samples,
-                mstate.sched_calls, mstate.step, mstate.ring,
-                mstate.params, mstate.tparams, *args,
-            )
+                *leaves(mstate), *args)
             # direct construction (not dataclasses.replace): this wrapper
             # runs once per step on the host, keep it lean
             return out, MonitorState(
@@ -683,6 +686,8 @@ class Monitor:
             else getattr(wrapped, "__name__", "fn"))
         stepped.monitor = self
         stepped._cjit = cjit
+        stepped.lower = lambda mstate, *args: cjit.lower(
+            *leaves(mstate), *args)
         return stepped
 
     def shard_wrap(self, fn: Callable, mesh, in_specs, out_specs) -> Callable:
@@ -691,14 +696,13 @@ class Monitor:
 
         ``in_specs``/``out_specs`` describe ``fn``'s own args/outputs; the
         MonitorState is replicated automatically (counters are identical on
-        every shard after the in-body ``psum``).  ``check_rep=False`` is
+        every shard after the in-body ``psum``).  ``check_vma=False`` is
         required: the probe path's mask ``lax.cond`` confuses shard_map's
         replication checker (a JAX limitation, not a semantic one — the
         2-device test asserts exact equality with the per-shard sum).
         """
         import copy
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         mon = self
@@ -713,9 +717,9 @@ class Monitor:
                 in_specs, (tuple, list)):
             in_specs = (in_specs,)
         rep = PartitionSpec()
-        sharded = shard_map(
+        sharded = jax.shard_map(
             wrapped, mesh=mesh, in_specs=(rep,) + tuple(in_specs),
-            out_specs=(out_specs, rep), check_rep=False,
+            out_specs=(out_specs, rep), check_vma=False,
         )
         sharded.monitor = mon
         return sharded

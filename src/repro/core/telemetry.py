@@ -398,6 +398,13 @@ def _close_all_planes() -> None:  # pragma: no cover - atexit path
             pass
 
 
+def _start_host_copies(tree) -> None:
+    """Start non-blocking device→host copies of every leaf of ``tree``; the
+    caller gathers them with ``np.asarray`` (pure transfers, no compute)."""
+    for x in jax.tree.leaves(tree):
+        x.copy_to_host_async()
+
+
 class TelemetryPlane:
     """Owns the telemetry cadence, the drain thread, and the sink fan-out.
 
@@ -460,6 +467,7 @@ class TelemetryPlane:
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
+        self._drain_error: Exception | None = None
         self._closed = False
 
         global _ATEXIT_INSTALLED
@@ -636,14 +644,7 @@ class TelemetryPlane:
         # tokens are outputs, not samples: an overrun is data loss, so the
         # engine sizes depth > steps-per-drain; account it loudly anyway
         self.dropped_tokens += first - self._tok_cursor
-        try:
-            jax.tree.map(
-                lambda x: x.copy_to_host_async()
-                if hasattr(x, "copy_to_host_async") else None,
-                (ring.steps, ring.toks, ring.live),
-            )
-        except Exception:  # pragma: no cover - backend-dependent
-            pass
+        _start_host_copies((ring.steps, ring.toks, ring.live))
         steps_h = np.asarray(ring.steps)
         toks_h = np.asarray(ring.toks)
         live_h = np.asarray(ring.live)
@@ -702,7 +703,10 @@ class TelemetryPlane:
         return self._last_step
 
     def flush(self) -> list[TelemetrySnapshot]:
-        """Synchronously drain every pending ring slot and flush sinks."""
+        """Synchronously drain every pending ring slot and flush sinks.
+
+        Raises if the background drain thread died since the last call."""
+        self._raise_drain_error()
         snaps = self._drain_once()
         for s in list(self.sinks):
             try:
@@ -714,7 +718,10 @@ class TelemetryPlane:
         return snaps
 
     def close(self) -> None:
-        """Stop the drain thread, flush remaining slots, close sinks."""
+        """Stop the drain thread, flush remaining slots, close sinks.
+
+        Raises, after closing the sinks, if the background drain thread
+        died since the last ``flush()``."""
         if self._closed:
             return
         self._closed = True
@@ -723,17 +730,20 @@ class TelemetryPlane:
         t = self._thread
         if t is not None and t.is_alive():
             t.join(timeout=5.0)
-        self._drain_once()
-        for s in list(self.sinks):
-            try:
-                s.close()
-            except Exception:
-                pass
+        try:
+            self._raise_drain_error()
+            self._drain_once()
+        finally:
+            for s in list(self.sinks):
+                try:
+                    s.close()
+                except Exception:
+                    pass
 
     # -- drain machinery ---------------------------------------------------
     def _ensure_thread(self) -> None:
-        if self._closed or (self._thread is not None and
-                            self._thread.is_alive()):
+        if self._closed or self._drain_error is not None or (
+                self._thread is not None and self._thread.is_alive()):
             return
         self._thread = threading.Thread(
             target=self._drain_loop, name="scalpel-telemetry-drain",
@@ -747,8 +757,18 @@ class TelemetryPlane:
             self._wake.clear()
             try:
                 self._drain_once()
-            except Exception:  # pragma: no cover - keep draining on errors
-                pass
+            except Exception as e:
+                # kept for the caller's thread: flush()/close() re-raise it
+                self._drain_error = e
+                return
+
+    def _raise_drain_error(self) -> None:
+        """Re-raise (once) an exception the background drain died with."""
+        err, self._drain_error = self._drain_error, None
+        if err is not None:
+            raise RuntimeError(
+                "scalpel telemetry: the background drain thread failed"
+            ) from err
 
     def _drain_once(self) -> list[TelemetrySnapshot]:
         # time INSIDE the lock: drain_seconds is the budget loop's measured
@@ -804,26 +824,16 @@ class TelemetryPlane:
                 self._last_step = snap.step
                 out.append(snap)
 
-            def start_copies(tree) -> None:
-                # Non-blocking device→host: start the copies, then gather
-                # on THIS (drain) thread — the step loop never waits.
-                try:
-                    jax.tree.map(
-                        lambda x: x.copy_to_host_async()
-                        if hasattr(x, "copy_to_host_async") else None,
-                        tree,
-                    )
-                except Exception:  # pragma: no cover - backend-dependent
-                    pass
-
+            # Non-blocking device→host: start the copies, then gather on
+            # THIS (drain) thread — the step loop never waits.
             if pending == 1:
-                start_copies((ring.last, ring.last_step))
+                _start_host_copies((ring.last, ring.last_step))
                 state = jax.tree.map(np.asarray, ring.last)
                 emit(head - 1, int(np.asarray(ring.last_step)), state)
                 self.slots_copied += 1
             else:
-                start_copies((ring.steps, ring.calls, ring.values,
-                              ring.samples))
+                _start_host_copies((ring.steps, ring.calls, ring.values,
+                                    ring.samples))
                 steps_h = np.asarray(ring.steps)
                 calls_h = np.asarray(ring.calls)
                 values_h = np.asarray(ring.values)

@@ -178,7 +178,8 @@ def lane_mesh(n_shards: int, axis: str = "lanes"):
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
     import numpy as np
 
-    return jax.sharding.Mesh(np.asarray(devs[:n_shards]), (axis,))
+    return jax.sharding.Mesh(np.asarray(devs[:n_shards]), (axis,),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def axis_size(name: str) -> int:

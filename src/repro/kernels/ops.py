@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) every kernel runs in interpret mode — the kernel
-body executes in Python for correctness validation; on a TPU backend the
-same calls lower to Mosaic.  Wrappers also adapt model-layer calling
-conventions (GQA [b,s,h,d]) to the kernel contracts ([bh,s,d]).
+Every kernel lowers to Mosaic, for a TPU.  Interpret mode (the kernel body
+executed by the Pallas interpreter, for correctness checks off the chip)
+runs only when a caller passes ``interpret=True`` — there is no backend
+guess, so a kernel that cannot run where it was called fails loudly instead
+of silently becoming an interpreter.  Wrappers also adapt model-layer
+calling conventions (GQA [b,s,h,d]) to the kernel contracts ([bh,s,d]).
 """
 from __future__ import annotations
 
@@ -20,17 +22,11 @@ from . import ssm_scan as _ssm
 SCHEDULES = ("cache_blocked", "panel_streaming")
 
 
-def _interpret(flag: bool | None) -> bool:
-    if flag is not None:
-        return flag
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # fused probe-moment reduction (the monitoring hot path)
 # ---------------------------------------------------------------------------
 
-# Below this many elements the grid/pad bookkeeping outweighs the fused
+# Below this many elements the grid bookkeeping outweighs the fused
 # sweep; the probe path uses the jnp fallback instead.
 MIN_PALLAS_MOMENT_NUMEL = 1 << 15
 
@@ -38,17 +34,50 @@ MIN_PALLAS_MOMENT_NUMEL = 1 << 15
 @functools.partial(
     jax.jit, static_argnames=("block_rows", "interpret", "with_entropy")
 )
-def probe_moments(x, *, block_rows: int = 256, interpret: bool | None = None,
+def probe_moments(x, *, block_rows: int = 256, interpret: bool = False,
                   with_entropy: bool = False):
     """Raw probe-moment vector f32[8] (f32[9] with the plan-requested
     ``ent_sum`` channel; see probe_reduce.MOMENTS/MOMENTS_ENT) of ``x``.
 
-    Single tiled pass over the tensor: interpret mode on CPU, Mosaic on TPU.
+    Single tiled pass over the tensor, lowered to Mosaic (``interpret=True``
+    runs the Pallas interpreter instead, for checks off the chip).
     """
-    return _pr.moments_pallas(
-        x, block_rows=block_rows, interpret=_interpret(interpret),
-        with_entropy=with_entropy,
-    )
+    return _pr.moments_pallas(x, block_rows=block_rows, interpret=interpret,
+                              with_entropy=with_entropy)
+
+
+def _mesh_split(x):
+    """``(mesh, axes)`` when ``x`` is traced under an ambient multi-device
+    mesh (``dist.partition.sharding_ctx``) that no ``shard_map`` binds:
+    ``axes`` are the mesh axes its leading (batch) dim splits over, empty
+    when none divides it.  ``(None, ())`` everywhere else."""
+    from repro.dist import partition
+
+    mesh = partition.current_mesh()
+    if mesh is None or mesh.size == 1 or partition.bound_axes(
+            mesh.axis_names):
+        return None, ()
+    lead = partition.relaxed_pspec(x.shape[:1], ("batch",), mesh)[0]
+    if lead is None:
+        return mesh, ()
+    return mesh, (lead,) if isinstance(lead, str) else tuple(lead)
+
+
+def _sharded_probe_moments(x, mesh, axes, with_entropy: bool):
+    """The kernel on each device's shard of ``x``, combined: sums and
+    counts by ``psum``, ``max_abs`` by ``pmax``, ``numel`` from the global
+    shape.  A Mosaic kernel cannot be partitioned by the compiler, so a
+    sharded jit runs it per shard under ``shard_map``."""
+    from jax.sharding import PartitionSpec
+
+    def per_shard(xs):
+        v = probe_moments(xs, with_entropy=with_entropy)
+        top = jax.lax.pmax(v[_pr.M_MAX_ABS], axes)
+        return jax.lax.psum(v, axes).at[_pr.M_MAX_ABS].set(top)
+
+    v = jax.shard_map(per_shard, mesh=mesh, in_specs=PartitionSpec(axes),
+                      out_specs=PartitionSpec(), check_vma=False)(x)
+    return v.at[_pr.M_NUMEL].set(jnp.float32(x.size))
 
 
 def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
@@ -58,9 +87,11 @@ def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
     for the active event set — the sweep computes nothing outside it (plus
     the free trace-time constants ``numel``/``rows``).
 
-    Policy: the Pallas kernel on TPU for large float tensors; the fused-jnp
-    fallback for tiny/oddly-shaped/non-float tensors and on CPU, where
-    interpret-mode Pallas would be a correctness tool, not a fast path.
+    Policy: the Pallas kernel on TPU for large float tensors — per shard
+    under a sharded jit, where the leading dim splits over the mesh; the
+    fused-jnp fallback for tiny/oddly-shaped/non-float tensors, for tensors
+    a mesh cannot split that way, and on CPU, where interpret-mode Pallas
+    would be a correctness tool, not a fast path.
     """
     if use_pallas is None:
         use_pallas = (
@@ -68,9 +99,13 @@ def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
             and jnp.issubdtype(x.dtype, jnp.floating)
             and x.size >= MIN_PALLAS_MOMENT_NUMEL
         )
-    if use_pallas:
+    mesh, axes = _mesh_split(x) if use_pallas else (None, ())
+    if use_pallas and (mesh is None or axes):
         with_entropy = "ent_sum" in set(names)
-        vec = probe_moments(x, with_entropy=with_entropy)
+        if mesh is None:
+            vec = probe_moments(x, with_entropy=with_entropy)
+        else:
+            vec = _sharded_probe_moments(x, mesh, axes, with_entropy)
         chans = _pr.MOMENTS_ENT if with_entropy else _pr.MOMENTS
         out = dict(zip(chans, vec))
         out.update(_pr.static_channel_values(x.shape))  # exact numel + rows
@@ -86,16 +121,15 @@ def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
     jax.jit, static_argnames=("schedule", "bm", "bn", "bk", "interpret")
 )
 def matmul(a, b, schedule: str = "panel_streaming", *, bm: int = 256,
-           bn: int = 256, bk: int = 256, interpret: bool | None = None):
+           bn: int = 256, bk: int = 256, interpret: bool = False):
     """C = A @ B via the named Pallas schedule (f32 out)."""
-    interp = _interpret(interpret)
     if schedule == "cache_blocked":
         return _gemm.cache_blocked_matmul(
-            a, b, bm=bm, bn=bn, bk=bk, interpret=interp
+            a, b, bm=bm, bn=bn, bk=bk, interpret=interpret
         )
     if schedule == "panel_streaming":
         return _gemm.panel_streaming_matmul(
-            a, b, bm=bm, bn=bn, interpret=interp
+            a, b, bm=bm, bn=bn, interpret=interpret
         )
     raise KeyError(f"unknown schedule {schedule!r}; have {SCHEDULES}")
 
@@ -117,7 +151,7 @@ def matmul_cost(schedule: str, m: int, n: int, k: int, *, bm: int = 256,
 )
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_kv: int = 1024,
-                    interpret: bool | None = None):
+                    interpret: bool = False):
     """Model-layer convention: q [b,sq,h,d]; k,v [b,sk,kvh,d] (GQA ok)."""
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
@@ -130,7 +164,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     out = _fa.flash_attention_bhsd(
         qf, kf, vf, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=_interpret(interpret),
+        block_q=block_q, block_kv=block_kv, interpret=interpret,
     )
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 
@@ -173,8 +207,8 @@ def flash_attention_cost(b, sq, sk, h, d, *, causal=True, block_q=512,
     jax.jit, static_argnames=("chunk", "bd", "interpret")
 )
 def ssm_scan(log_a, b_in, *, chunk: int = 256, bd: int = 512,
-             interpret: bool | None = None):
+             interpret: bool = False):
     """h_t = exp(log_a_t)*h_{t-1} + b_t over axis 1. [B,S,D] -> [B,S,D]."""
     return _ssm.ssm_scan_chunked(
-        log_a, b_in, chunk=chunk, bd=bd, interpret=_interpret(interpret)
+        log_a, b_in, chunk=chunk, bd=bd, interpret=interpret
     )

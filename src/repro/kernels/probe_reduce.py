@@ -17,15 +17,15 @@ same batching-of-counter-collection argument appears in Scaler and LIKWID:
 monitoring stays lightweight only if counter reads share their passes over
 the data.
 
-Layout: the input is flattened (no copy) and a 1-D grid walks flat blocks
-of block_rows*128 elements, retiled to (sublanes, lanes) in-kernel; partial
-moments accumulate into a (1, 8) f32 output block that every grid step maps
-to (revisiting semantics keep it VMEM-resident).  The last block may run
-ragged past the end of the array; out-of-bounds lanes are masked via the
-global element index, so non-tile-aligned shapes are exact — and never pay
-a pad copy.  NaNs propagate through sum/sum_sq/sum_abs/max_abs exactly as
-they do through the unfused ``jnp`` reductions, so fused and legacy event
-values agree even on poisoned tensors.
+Layout: the input is viewed as (lead, rows, cols) — its leading axes
+collapsed, which moves no data — and a 3-D grid walks 2-D (rows, cols)
+tiles; partial moments accumulate into a (1, 8) f32 output block that every
+grid step maps to (revisiting semantics keep it VMEM-resident).  Edge tiles
+may run ragged past the end of the array; out-of-bounds elements are masked
+via their (row, column) index, so non-tile-aligned shapes are exact — and
+never pay a pad copy.  NaNs propagate through sum/sum_sq/sum_abs/max_abs
+exactly as they do through the unfused ``jnp`` reductions, so fused and
+legacy event values agree even on poisoned tensors.
 
 ``jax.experimental.pallas`` is imported lazily so this module (which owns
 the moment-vector contract) stays importable from the core event registry
@@ -86,42 +86,51 @@ def static_channel_values(shape) -> dict:
     return {"numel": jnp.float32(numel), "rows": jnp.float32(rows)}
 
 
-def _moment_kernel(x_ref, o_ref, *, numel: int, block_rows: int,
+def _moment_kernel(x_ref, o_ref, *, rows: int, cols: int, block: tuple,
                    with_entropy: bool):
-    """One grid step: fold a block_rows*LANES flat block into the accumulator.
+    """One grid step: fold one (block_rows, block_cols) tile into the
+    accumulator.
 
-    The final grid step may run past the end of the input (ragged tail) —
-    out-of-bounds lanes carry unspecified values, so every use of ``x`` is
-    select-masked by the global element index before any reduction.
+    Grid axes are (leading slab, row block, column block).  Tiles on the
+    bottom/right edge may run past the end of the array (ragged tail) —
+    out-of-bounds elements carry unspecified values, so when the block does
+    not tile the array every use of ``x`` is select-masked by the element's
+    (row, column) index before any reduction.
     """
     import jax.experimental.pallas as pl
 
     n_chan = len(MOMENTS_ENT) if with_entropy else len(MOMENTS)
-    i = pl.program_id(0)
+    lead, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    br, bc = block
 
-    @pl.when(i == 0)
+    @pl.when((lead == 0) & (i == 0) & (j == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # retile the flat block to (sublanes, lanes) — TPU wants 2-D iota
-    x = x_ref[...].reshape(block_rows, LANES).astype(jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    idx = (i * block_rows + rows) * LANES + cols
-    valid = idx < numel
+    x = x_ref[...].astype(jnp.float32)
+    if rows % br or cols % bc:
+        r = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) + i * br
+        c = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * bc
+        valid = (r < rows) & (c < cols)
+        xm = jnp.where(valid, x, 0.0)  # NaN/Inf survive in valid lanes
+    else:
+        valid, xm = None, x
 
-    xm = jnp.where(valid, x, 0.0)  # NaN/Inf survive in valid lanes
+    def count(pred):
+        if valid is not None:
+            pred = valid & pred
+        return jnp.sum(jnp.where(pred, 1.0, 0.0))
+
     ax = jnp.abs(xm)
-    one = jnp.float32(1.0)
     zero = jnp.float32(0.0)
     channels = [
         jnp.sum(xm),
         jnp.sum(xm * xm),
         jnp.sum(ax),
         zero,  # max channel handled below (max, not add)
-        jnp.sum(jnp.where(valid & (x == 0), one, zero)),
-        jnp.sum(jnp.where(valid & jnp.isnan(x), one, zero)),
-        jnp.sum(jnp.where(valid & jnp.isinf(x), one, zero)),
+        count(x == 0),
+        count(jnp.isnan(x)),
+        count(jnp.isinf(x)),
         zero,  # numel is a trace-time constant, written by the wrapper:
         # accumulating the mask sum in f32 would round above 2^24 elements
     ]
@@ -137,36 +146,58 @@ def _moment_kernel(x_ref, o_ref, *, numel: int, block_rows: int,
     o_ref[...] = jnp.where(chan == M_MAX_ABS, new_max, acc + part)
 
 
+# Column tile cap: a multiple of LANES, so a row wider than this is walked
+# in lane-aligned tiles with a masked ragged tail (vocab-wide logits).
+MAX_BLOCK_COLS = 8 * LANES
+# Row tiles are multiples of this: the sublane tile of the narrowest dtype
+# a probe sees (32 rows for 8-bit, 16 for bf16, 8 for f32).
+ROW_ALIGN = 32
+
+
+def _block_shape(rows: int, cols: int, block_rows: int) -> tuple[int, int]:
+    """(block_rows, block_cols) legal for Mosaic: each dim is either the
+    full array dim or a multiple of its hardware tile."""
+    bc = cols if cols <= MAX_BLOCK_COLS else MAX_BLOCK_COLS
+    br = max(ROW_ALIGN, block_rows // ROW_ALIGN * ROW_ALIGN)
+    return (rows if rows <= br else br), bc
+
+
 def moments_pallas(x, *, block_rows: int = 256, interpret: bool = False,
                    with_entropy: bool = False):
     """Raw moment vector f32[8] (f32[9] with entropy) in a single tiled pass.
 
-    The input is only flattened (a layout-preserving reshape, not a copy);
-    non-aligned sizes are handled by letting the LAST grid step run ragged
-    past the end of the array and masking in-kernel — no ``jnp.pad``, which
-    would re-materialize the whole tensor and double the HBM traffic the
-    kernel exists to remove.  ``with_entropy`` (static, plan-driven) appends
-    the ``ent_sum`` channel to the same sweep.
+    The input is viewed as ``(lead, rows, cols)`` — every axis before the
+    last two collapsed into ``lead``, which is a layout-preserving reshape
+    on TPU (its tiled layout covers only the two minor dims), never a copy.
+    The grid walks ``lead`` x row tiles x column tiles; tiles past the end
+    of a ragged dim are masked in-kernel — no ``jnp.pad``, which would
+    re-materialize the whole tensor and double the HBM traffic the kernel
+    exists to remove.  Blocks are 2-D (``lead`` is squeezed), so bf16 and
+    f32 inputs lower alike, and ``jax.vmap`` (the serve engine probes each
+    lane inside one) only prepends a grid axis.  ``with_entropy`` (static,
+    plan-driven) appends the ``ent_sum`` channel to the same sweep.
     """
     n = int(x.size)
     if n == 0:
         return moments_ref(x, with_entropy=with_entropy)
     n_chan = len(MOMENTS_ENT) if with_entropy else len(MOMENTS)
-    xf = x.reshape(-1)
-    block = block_rows * LANES
-    grid = (n + block - 1) // block
+    shape = (1, 1) + tuple(x.shape)
+    rows, cols = shape[-2], shape[-1]
+    x3 = x.reshape(-1, rows, cols)
+    br, bc = _block_shape(rows, cols, block_rows)
+    grid = (x3.shape[0], -(-rows // br), -(-cols // bc))
 
     import jax.experimental.pallas as pl
 
     out = pl.pallas_call(
-        functools.partial(_moment_kernel, numel=n, block_rows=block_rows,
-                          with_entropy=with_entropy),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1, n_chan), lambda i: (0, 0)),
+        functools.partial(_moment_kernel, rows=rows, cols=cols,
+                          block=(br, bc), with_entropy=with_entropy),
+        grid=grid,
+        in_specs=[pl.BlockSpec((None, br, bc), lambda l, i, j: (l, i, j))],
+        out_specs=pl.BlockSpec((1, n_chan), lambda l, i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n_chan), jnp.float32),
         interpret=interpret,
-    )(xf)
+    )(x3)
     return out[0].at[M_NUMEL].set(jnp.float32(n))
 
 

@@ -17,6 +17,9 @@ Usage:
 """
 import os
 
+# a 512-device pod simulated on the host CPU: pinned there, so the script
+# never starts on (or waits for) an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402

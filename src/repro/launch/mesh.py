@@ -5,6 +5,11 @@ never touches jax device state.  The dry-run (launch/dryrun.py) sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import; everything else sees the real device count.
 
+Every mesh is built with Auto axis types: the model code places tensors
+with ``with_sharding_constraint`` (``dist/partition.shard``) and leaves the
+rest to the compiler, which Explicit axes — ``jax.make_mesh``'s default in
+the installed JAX — refuse.
+
 Mesh axes:
   data  — batch / FSDP axis (16-way per pod)
   model — TP / vocab / expert axis (16-way, maps to the high-bandwidth ring)
@@ -13,12 +18,19 @@ Mesh axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` over all local devices, every axis Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
@@ -26,7 +38,7 @@ def make_host_mesh(data: int | None = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = max(1, n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def describe(mesh) -> str:

@@ -60,7 +60,6 @@ import copy
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import telemetry as telemetry_lib
@@ -109,8 +108,8 @@ class DecodeDriver:
             if not sharded:
                 return jax.jit(core, donate_argnums=donate)
             return jax.jit(
-                shard_map(core, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False),
+                jax.shard_map(core, mesh=mesh, in_specs=in_specs,
+                              out_specs=out_specs, check_vma=False),
                 donate_argnums=donate,
             )
 
@@ -287,10 +286,7 @@ class DecodeDriver:
         the bucket count, not by distinct prompt lengths."""
 
         def n(f):
-            try:
-                return int(f._cache_size())
-            except Exception:  # cache-stat API unavailable
-                return -1
+            return int(f._cache_size())
 
         return {
             "prefill_traces": n(self._prefill) + n(self._prefill_bucketed),

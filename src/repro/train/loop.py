@@ -12,6 +12,7 @@ full-CounterState device→host transfer inside the step loop.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable
@@ -23,6 +24,7 @@ from repro import core as scalpel
 from repro.checkpoint import CheckpointManager
 from repro.core.backends.host_time import HostTimer
 from repro.data import DataConfig, SyntheticLM, prefetch, shard_batch
+from repro.dist.partition import sharding_ctx
 from repro.models.registry import Arch
 from repro.optim import OptConfig
 from .step import TrainState, build_monitor_spec, make_train_megastep
@@ -59,7 +61,8 @@ class TrainLoopConfig:
 def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
         loop_cfg: TrainLoopConfig, mesh=None,
         on_report: Callable | None = None) -> dict[str, Any]:
-    """Train; returns summary dict (final loss, step times, reports)."""
+    """Train; returns summary dict (final loss, step times, reports, and
+    ``step``: the compiled megastep, whose ``lower`` gives its HLO)."""
     data = SyntheticLM(data_cfg)
     sample = data.batch_at(0)
     spec = build_monitor_spec(arch, sample)
@@ -205,7 +208,11 @@ def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
         # NEXT megastep boundary, so the adaptive loop reacts with up to K
         # steps of latency
         mstate = mon.sync(mstate, runtime=runtime)
-        (tstate, out), mstate = jit_step(mstate, batches, tstate)
+        # the mesh is ambient while the step traces: activations follow the
+        # logical-axis rules and the probe kernel runs per shard
+        with sharding_ctx(mesh) if mesh is not None else \
+                contextlib.nullcontext():
+            (tstate, out), mstate = jit_step(mstate, batches, tstate)
         inflight.append((last_step, out))
         # bounded in-flight dispatch: only the megastep leaving the window
         # is synchronized, so device and host overlap up to max_in_flight
@@ -260,5 +267,6 @@ def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
         "runtime": runtime,
         "state": tstate,
         "monitor": mstate,
+        "step": jit_step,
         "spec": spec,
     }

@@ -130,7 +130,7 @@ def test_elastic_restore_into_mesh(tmp_path):
     """Checkpoints restore under any mesh (1-device here) via logical axes."""
     from repro.dist.partition import sharding_ctx
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     path = str(tmp_path / "ck.npz")
     tree = {"w": jnp.ones((8, 4))}
     save_tree(path, tree)

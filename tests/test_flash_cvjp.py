@@ -81,7 +81,7 @@ def test_run_attention_head_padding_slices_back(cfg):
 
     q, k, v = _mk(1, 128, 128, 5, 5, 32)  # 5 heads: never divides 2
     want = ref.attention(q, k, v, causal=True)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
     with sharding_ctx(mesh):  # tp=1 -> no pad; sanity
         got = L.run_attention(cfg, q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)

@@ -36,6 +36,8 @@ FP = "ab" * 20
 
 def _env():
     env = dict(os.environ)
+    # simulated hosts run on the CPU: never contend for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
     return env

@@ -1,4 +1,5 @@
-"""Pallas kernel sweeps vs the pure-jnp oracles (interpret mode on CPU)."""
+"""Pallas kernel sweeps vs the pure-jnp oracles (interpret mode, requested
+explicitly)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +31,7 @@ def test_gemm_allclose(schedule, m, n, k, dtype):
     a = jax.random.normal(jax.random.PRNGKey(0), (m, k), dtype)
     b = jax.random.normal(jax.random.PRNGKey(1), (k, n), dtype)
     want = ref.matmul(a, b)
-    got = ops.matmul(a, b, schedule, bm=128, bn=128, bk=128)
+    got = ops.matmul(a, b, schedule, bm=128, bn=128, bk=128, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=TOL[dtype] * np.sqrt(k),
         rtol=TOL[dtype],
@@ -40,8 +41,9 @@ def test_gemm_allclose(schedule, m, n, k, dtype):
 def test_gemm_schedules_agree():
     a = jax.random.normal(jax.random.PRNGKey(2), (256, 256), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(3), (256, 256), jnp.float32)
-    c1 = ops.matmul(a, b, "cache_blocked", bm=128, bn=128, bk=128)
-    c2 = ops.matmul(a, b, "panel_streaming", bm=128, bn=128)
+    c1 = ops.matmul(a, b, "cache_blocked", bm=128, bn=128, bk=128,
+                   interpret=True)
+    c2 = ops.matmul(a, b, "panel_streaming", bm=128, bn=128, interpret=True)
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), atol=2e-4)
 
 
@@ -70,7 +72,8 @@ def test_gemm_property_any_blocking(m, n, k):
     a = jax.random.normal(jax.random.PRNGKey(4), (m, k), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(5), (k, n), jnp.float32)
     want = np.asarray(ref.matmul(a, b))
-    got = ops.matmul(a, b, "cache_blocked", bm=128, bn=128, bk=128)
+    got = ops.matmul(a, b, "cache_blocked", bm=128, bn=128, bk=128,
+                     interpret=True)
     np.testing.assert_allclose(np.asarray(got), want, atol=5e-4)
 
 
@@ -99,7 +102,7 @@ def test_flash_attention_allclose(b, sq, sk, h, kvh, d, causal, win):
     vr = jnp.repeat(v, h // kvh, axis=2)
     want = ref.attention(q, kr, vr, causal=causal, window=win)
     got = ops.flash_attention(q, k, v, causal=causal, window=win,
-                              block_q=64, block_kv=64)
+                              block_q=64, block_kv=64, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
@@ -110,7 +113,8 @@ def test_flash_attention_bf16(dtype):
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, d), dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d), dtype)
     want = ref.attention(q, k, v, causal=True)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_kv=64)
+    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_kv=64,
+                              interpret=True)
     assert got.dtype == dtype
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=3e-2
@@ -123,7 +127,7 @@ def test_flash_attention_block_shape_invariance():
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, d), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d), jnp.float32)
     outs = [
-        ops.flash_attention(q, k, v, block_q=bq, block_kv=bkv)
+        ops.flash_attention(q, k, v, block_q=bq, block_kv=bkv, interpret=True)
         for bq, bkv in [(64, 64), (128, 64), (64, 128), (256, 256)]
     ]
     for o in outs[1:]:
@@ -158,7 +162,7 @@ def test_ssm_scan_allclose(B, S, D, chunk, bd):
     ) * 0.3
     bb = jax.random.normal(jax.random.PRNGKey(1), (B, S, D))
     want = ref.ssm_scan(None, la, bb)
-    got = ops.ssm_scan(la, bb, chunk=chunk, bd=bd)
+    got = ops.ssm_scan(la, bb, chunk=chunk, bd=bd, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4
     )
@@ -168,8 +172,8 @@ def test_ssm_scan_chunk_invariance():
     B, S, D = 1, 256, 32
     la = -jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (B, S, D))) * 0.5
     bb = jax.random.normal(jax.random.PRNGKey(3), (B, S, D))
-    o1 = ops.ssm_scan(la, bb, chunk=64, bd=32)
-    o2 = ops.ssm_scan(la, bb, chunk=256, bd=16)
+    o1 = ops.ssm_scan(la, bb, chunk=64, bd=32, interpret=True)
+    o2 = ops.ssm_scan(la, bb, chunk=256, bd=16, interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
 
 
@@ -177,9 +181,11 @@ def test_ssm_scan_decay_identity():
     """log_a = -inf-ish -> h_t == b_t; log_a = 0 -> h_t = cumsum(b)."""
     B, S, D = 1, 64, 8
     bb = jax.random.normal(jax.random.PRNGKey(4), (B, S, D))
-    h_dead = ops.ssm_scan(jnp.full((B, S, D), -40.0), bb, chunk=32, bd=8)
+    h_dead = ops.ssm_scan(jnp.full((B, S, D), -40.0), bb, chunk=32, bd=8,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(h_dead), np.asarray(bb), atol=1e-5)
-    h_int = ops.ssm_scan(jnp.zeros((B, S, D)), bb, chunk=32, bd=8)
+    h_int = ops.ssm_scan(jnp.zeros((B, S, D)), bb, chunk=32, bd=8,
+                         interpret=True)
     np.testing.assert_allclose(
         np.asarray(h_int), np.cumsum(np.asarray(bb), axis=1), atol=1e-4
     )

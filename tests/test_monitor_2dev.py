@@ -49,7 +49,7 @@ def work(x):
 
 
 x = jnp.arange(16.0)
-mesh = jax.make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
 # ---- shard_map: per-shard collection, in-graph psum --------------------
 mon = scalpel.Monitor(spec)
@@ -112,13 +112,11 @@ mux_schedule_ok = bool(
 # did: counters psum-exact, sched_calls still the PER-SHARD base (feeding
 # the reduced totals through the scan carry would advance the set index 2x
 # per inner step and skip set 1 on every shard).
-from jax.experimental.shard_map import shard_map as _shard_map
-
 mm2 = scalpel.Monitor(mspec, counter_axes=("data",))
 mega = mm2.wrap(mwork, steps_per_commit=4)
-smega = jax.jit(_shard_map(
+smega = jax.jit(jax.shard_map(
     mega, mesh=mesh, in_specs=(P(), P("data")), out_specs=(P("data"), P()),
-    check_rep=False,
+    check_vma=False,
 ))
 _, mega_ms = smega(mm2.init(), x)
 mega_mux_ok = bool(
@@ -157,15 +155,13 @@ tmon = scalpel.Monitor(tspec, counter_axes=("data",))
 tstep = make_train_step(arch, opt, tspec, monitor=tmon)
 t0 = TrainState.create(arch, opt, jax.random.PRNGKey(0))
 
-from jax.experimental.shard_map import shard_map
-
 # NB: out_specs claims replication for tstate (per-shard grads are NOT
 # psum-ed here — this exercise is about the counters, which ARE)
-smap = shard_map(
+smap = jax.shard_map(
     tstep, mesh=mesh,
     in_specs=(P(), {"tokens": P("data"), "targets": P("data")}, P()),
     out_specs=(P(), P(), P()),
-    check_rep=False,
+    check_vma=False,
 )
 # no ambient sharding_ctx here: inside shard_map the model's logical-axis
 # constraints would name manual axes (counter_axes is explicit instead)

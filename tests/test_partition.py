@@ -17,7 +17,7 @@ from repro.dist.partition import (
 
 @pytest.fixture()
 def mesh1():
-    return jax.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def test_no_context_is_noop():
@@ -41,11 +41,12 @@ def test_pspec_duplicate_mesh_axis_suppressed(mesh1):
 
 
 def test_relaxation_drops_nondividing(mesh1):
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
     rules = {"mlp": ("model",)}
     ps = relaxed_pspec((7,), ("mlp",), mesh, rules)
     assert ps == PartitionSpec("model")  # 1 divides everything
-    mesh2 = jax.make_mesh((1,), ("data",))  # model axis absent
+    # model axis absent
+    mesh2 = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     ps2 = relaxed_pspec((7,), ("mlp",), mesh2, rules)
     assert ps2 == PartitionSpec(None)
 

@@ -190,6 +190,8 @@ print(json.dumps({
 @pytest.mark.slow
 def test_serve_sharded_2dev_subprocess():
     env = dict(os.environ)
+    # two simulated host devices: pinned to the CPU, off any accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src")
     )

@@ -5,7 +5,9 @@ Checks:
   * sharded train step runs under a (2,4) ("data","model") mesh,
   * counters are replicated and call counts match the unsharded run,
   * loss matches the single-device run (SPMD correctness),
-  * elastic re-mesh: a checkpoint saved under (2,4) restores under (4,2).
+  * elastic re-mesh: a checkpoint saved under (2,4) restores under (4,2),
+  * the probe kernel runs per data shard under the sharded jit and its
+    all-reduced moments equal the unsharded reference.
 """
 import json
 import os
@@ -16,6 +18,9 @@ import pytest
 
 SCRIPT = r"""
 import os
+# eight simulated host devices: pinned to the CPU so this child never
+# reaches for an accelerator its parent may hold
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax
@@ -27,6 +32,7 @@ from repro import core as scalpel
 from repro.configs import model_config
 from repro.data import DataConfig, SyntheticLM
 from repro.dist.partition import sharding_ctx, tree_shardings
+from repro.launch.mesh import auto_mesh
 from repro.models.registry import Arch
 from repro.optim import OptConfig, init_opt_state, opt_state_axes
 from repro.train.step import TrainState, build_monitor_spec, make_train_step
@@ -54,7 +60,7 @@ ref_calls = np.asarray(m1.calls).copy()
 # jit-SPMD: reductions over sharded tensors are already global, so the
 # Monitor's "auto" counter reduction resolves to a no-op (no bound axes)
 # and counters stay replicated — asserted equal to the unsharded run.
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = auto_mesh((2, 4), ("data", "model"))
 with mesh, sharding_ctx(mesh):
     params = arch.init(jax.random.PRNGKey(0))
     params = jax.device_put(
@@ -78,7 +84,7 @@ with mesh, sharding_ctx(mesh):
 
     # ---- elastic re-mesh: save under (2,4), restore under (4,2) ----
     save_tree("/tmp/spmd_ck.npz", t2.params)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = auto_mesh((4, 2), ("data", "model"))
 with mesh2, sharding_ctx(mesh2):
     like = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t2.params)
@@ -89,7 +95,25 @@ with mesh2, sharding_ctx(mesh2):
         for a, b in zip(jax.tree.leaves(t2.params), jax.tree.leaves(back))
     )
 
+# ---- the probe kernel per shard under a sharded jit ----
+# (interpreted here: a Mosaic kernel cannot be partitioned by the compiler,
+# so tensor_moments runs it on each data shard and all-reduces the moments)
+import functools
+from repro.kernels import ops, probe_reduce as pr
+ops.probe_moments = functools.partial(ops.probe_moments, interpret=True)
+xk = jax.random.normal(jax.random.PRNGKey(3), (8, 40, 130))
+xk = xk.at[1, 2, 3].set(0.0)
+names = pr.MOMENTS[:7]
+with sharding_ctx(mesh):
+    got = jax.jit(lambda t: ops.tensor_moments(t, names, use_pallas=True))(
+        jax.device_put(xk, NamedSharding(mesh, PartitionSpec("data"))))
+want = pr.moments_ref(xk)
+kernel_ok = all(
+    np.allclose(float(got[n]), float(want[i]), rtol=1e-5, atol=1e-3)
+    for i, n in enumerate(pr.MOMENTS))
+
 print(json.dumps({
+    "kernel_sharded_ok": bool(kernel_ok),
     "ref_loss": ref_loss,
     "spmd_loss": spmd_loss,
     "calls_match": bool((ref_calls == spmd_calls).all()),
@@ -111,5 +135,6 @@ def test_spmd_8dev_subprocess(tmp_path):
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["calls_match"], res
+    assert res["kernel_sharded_ok"], res
     assert res["elastic_ok"], res
     assert abs(res["ref_loss"] - res["spmd_loss"]) < 5e-2, res

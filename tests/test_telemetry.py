@@ -287,6 +287,30 @@ def test_background_drain_thread_runs_without_flush():
     plane.close()
 
 
+@pytest.mark.parametrize("surface", ["flush", "close"])
+def test_background_drain_failure_reraised_on_caller(monkeypatch, surface):
+    """A drain that dies on the background thread is not dropped: the next
+    flush()/close() re-raises it on the caller's thread, exactly once."""
+    spec = _spec()
+    plane = T.TelemetryPlane(spec, depth=8, cadence=1, interval_s=0.005)
+    cs = _bump(CounterState.zeros(spec))
+    plane.append(cs, step=1)  # the first append drains on this thread
+
+    def broken():
+        raise ValueError("transfer failed")
+
+    monkeypatch.setattr(plane, "_drain_once_inner", broken)
+    plane.append(cs, step=2)
+    deadline = time.time() + 5.0
+    while plane._drain_error is None and time.time() < deadline:
+        time.sleep(0.005)
+    with pytest.raises(RuntimeError, match="drain thread failed") as ei:
+        getattr(plane, surface)()
+    assert isinstance(ei.value.__cause__, ValueError)
+    monkeypatch.undo()
+    plane.close()  # reported once: a later close is clean
+
+
 def test_jsonl_sink_buffers_and_flushes(tmp_path):
     spec = _spec()
     path = str(tmp_path / "t.jsonl")
