@@ -710,6 +710,7 @@ import numpy as np
 from repro import core as scalpel
 from repro.core.context import EventSpec, MonitorSpec, ScopeContext
 from repro.dist.partition import sharding_ctx
+from repro.launch.mesh import auto_mesh
 
 assert jax.device_count() == 2, jax.devices()
 EVENTS = %r
@@ -728,7 +729,7 @@ def work(x):
 from jax.sharding import PartitionSpec as P
 
 mon = scalpel.Monitor(spec)
-mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+mesh = auto_mesh((2,), ("data",))
 with sharding_ctx(mesh):
     step = jax.jit(mon.shard_wrap(work, mesh, in_specs=P("data"),
                                   out_specs=P("data")))

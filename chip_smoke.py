@@ -199,6 +199,7 @@ def train_phase(cfg, *, seq: int, batch: int, steps: int = 8,
 
     from repro.data import DataConfig, SyntheticLM
 
+    # fit reports and closes its runtime: a failed drain raises there
     out = train_run(cfg, seq=seq, batch=batch, steps=steps, k=k)
     losses = np.asarray(out["losses"])
     require(losses.shape == (steps,) and np.isfinite(losses).all(), losses)
@@ -262,9 +263,16 @@ def serve_run(arch, params, prompts, *, n_lanes: int, cache_len: int,
     lane_sum = sum(int(np.sum(results[r].counters.calls)) for r in rids)
     agg = int(np.sum(np.asarray(eng.counters.calls)))
     require(lane_sum == agg, (lane_sum, agg))
+    # the final drain: close() re-raises a failed background drain
+    eng.runtime.close()
+    step = int(eng.lstate.step)
+    drained = int(np.sum(plane.last_state.calls))
+    require(plane.last_step == step and drained == agg,
+            (plane.last_step, step, drained, agg))
     log(f"  lane_shards={lane_shards}: {len(rids)} requests x {max_new} "
         f"tokens on {n_lanes} lanes in {eng.stats['megasteps']} megasteps, "
-        f"0 dropped, lane counter calls sum {lane_sum} == aggregate {agg}")
+        f"0 dropped, lane counter calls sum {lane_sum} == aggregate {agg} "
+        f"== drained at step {step}")
     return eng, rids, results
 
 

@@ -60,6 +60,22 @@ def _kernel_ops():
     return _KOPS
 
 
+def _partitioned_trace() -> bool:
+    """True while tracing under an ambient multi-device mesh
+    (``dist.partition.sharding_ctx``) with some axis of size > 1 that no
+    ``shard_map`` binds: the compiler will partition the program, and a
+    Mosaic kernel cannot be partitioned.  Probes then take XLA's fused
+    reduction, which the partitioner splits for whatever sharding the
+    tensor has — no reshard to fit a kernel's per-shard layout."""
+    from repro.dist import partition
+
+    mesh = partition.current_mesh()
+    if mesh is None:
+        return False
+    split = [a for a in mesh.axis_names if mesh.shape[a] > 1]
+    return len(partition.bound_axes(split)) < len(split)
+
+
 def _stack() -> list:
     if not hasattr(_TLS, "stack"):
         _TLS.stack = []
@@ -204,9 +220,11 @@ class Collector:
                 # (sets-dependent graphs are the price; only the selected
                 # branch executes at run time).
                 _kops = _kernel_ops()
+                use_pallas = False if _partitioned_trace() else None
                 moms = {
                     sw.tensor: _kops.tensor_moments(ts[sw.tensor],
-                                                    sw.channels)
+                                                    sw.channels,
+                                                    use_pallas=use_pallas)
                     for sw in pl.sweeps
                 }
                 vs = []

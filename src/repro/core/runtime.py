@@ -30,6 +30,7 @@ import atexit
 import signal
 import threading
 import time
+import traceback
 from typing import Callable
 
 import jax
@@ -87,7 +88,7 @@ class ScalpelRuntime:
         if install_signal:
             signal.signal(signal.SIGUSR1, self._on_sigusr1)
         if report_at_exit:
-            atexit.register(self._exit_report)
+            atexit.register(telemetry_lib.exit_hook(self._exit_report))
         if graceful_shutdown:
             self.install_shutdown()
 
@@ -245,7 +246,7 @@ class ScalpelRuntime:
         if self._shutdown_installed:
             return
         self._shutdown_installed = True
-        atexit.register(self.shutdown)
+        atexit.register(telemetry_lib.exit_hook(self.shutdown))
         for sig in signals:
             try:
                 self._prev_handlers[int(sig)] = signal.signal(
@@ -254,7 +255,12 @@ class ScalpelRuntime:
                 pass
 
     def _on_shutdown_signal(self, signum, frame):
-        self.shutdown()
+        try:
+            self.shutdown()
+        except Exception:
+            # the process is ending on this signal either way: print the
+            # failed final drain before the chained handler ends it
+            traceback.print_exc()
         prev = self._prev_handlers.get(int(signum), signal.SIG_DFL)
         if callable(prev):
             prev(signum, frame)
@@ -270,15 +276,15 @@ class ScalpelRuntime:
         """Graceful shutdown: flush the ring, drain pending snapshots,
         emit a final report, then close.  Idempotent with ``close()`` —
         whichever runs first wins and the other is a no-op.  Returns the
-        final report text (None if already closed)."""
+        final report text (None if already closed); a failed drain is
+        raised after the close."""
         if self._closed:
             return None
         try:
             report = self.report("ScALPEL final report")
             print(report)
-        except Exception:  # pragma: no cover - shutdown robustness
-            report = None
-        self.close()
+        finally:
+            self.close()
         return report
 
     def close(self) -> None:
@@ -370,7 +376,4 @@ class ScalpelRuntime:
             # an explicit close() already flushed and closed the sinks; the
             # atexit pass must not re-drive them
             return
-        try:
-            print(self.report())
-        except Exception:  # pragma: no cover - atexit robustness
-            pass
+        print(self.report())

@@ -41,9 +41,11 @@ from __future__ import annotations
 
 import atexit
 import dataclasses
+import os
 import sys
 import threading
 import time
+import traceback
 import weakref
 from typing import Any, Callable
 
@@ -390,12 +392,35 @@ _PLANES: "weakref.WeakSet[TelemetryPlane]" = weakref.WeakSet()
 _ATEXIT_INSTALLED = False
 
 
-def _close_all_planes() -> None:  # pragma: no cover - atexit path
+def exit_hook(fn: Callable[[], Any]) -> Callable[[], None]:
+    """``fn`` as an atexit hook whose failure fails the process.
+
+    Python prints an exception raised in an atexit hook and still exits 0,
+    so a drain that failed during the final flush would pass unseen: the
+    hook prints the traceback and ends the process with status 1."""
+
+    def hook() -> None:
+        try:
+            fn()
+        except Exception:
+            traceback.print_exc()
+            sys.stdout.flush()
+            sys.stderr.flush()
+            os._exit(1)
+
+    return hook
+
+
+def _close_all_planes() -> None:
+    """Close every live plane; raise the first failure after all closed."""
+    first = None
     for p in list(_PLANES):
         try:
             p.close()
-        except Exception:
-            pass
+        except Exception as e:
+            first = first or e
+    if first is not None:
+        raise first
 
 
 def _start_host_copies(tree) -> None:
@@ -473,7 +498,7 @@ class TelemetryPlane:
         global _ATEXIT_INSTALLED
         _PLANES.add(self)
         if not _ATEXIT_INSTALLED:
-            atexit.register(_close_all_planes)
+            atexit.register(exit_hook(_close_all_planes))
             _ATEXIT_INSTALLED = True
 
     # -- configuration ----------------------------------------------------

@@ -164,9 +164,8 @@ def lane_mesh(n_shards: int, axis: str = "lanes"):
 
     Unlike the training mesh (launch/mesh.py), a serve mesh may use a
     strict SUBSET of the local devices — a 2-way lane mesh on an 8-device
-    host leaves the rest to other engines — so this builds ``jax.Mesh``
-    directly from the first ``n_shards`` devices rather than going through
-    ``make_mesh`` (which wants them all).
+    host leaves the rest to other engines — so this builds the mesh from
+    the first ``n_shards`` devices rather than from all of them.
     """
     if n_shards < 1:
         raise ValueError(f"lane mesh needs >= 1 shard, got {n_shards}")
@@ -176,10 +175,9 @@ def lane_mesh(n_shards: int, axis: str = "lanes"):
             f"lane mesh needs {n_shards} devices, have {len(devs)} — "
             f"reduce ServeConfig.lane_shards (or force host devices via "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    import numpy as np
+    from repro.launch.mesh import auto_mesh
 
-    return jax.sharding.Mesh(np.asarray(devs[:n_shards]), (axis,),
-                             axis_types=(jax.sharding.AxisType.Auto,))
+    return auto_mesh((n_shards,), (axis,), devices=devs[:n_shards])
 
 
 def axis_size(name: str) -> int:

@@ -32,52 +32,19 @@ MIN_PALLAS_MOMENT_NUMEL = 1 << 15
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_rows", "interpret", "with_entropy")
+    jax.jit, static_argnames=("block_elems", "interpret", "with_entropy")
 )
-def probe_moments(x, *, block_rows: int = 256, interpret: bool = False,
-                  with_entropy: bool = False):
+def probe_moments(x, *, block_elems: int = _pr.BLOCK_ELEMS,
+                  interpret: bool = False, with_entropy: bool = False):
     """Raw probe-moment vector f32[8] (f32[9] with the plan-requested
     ``ent_sum`` channel; see probe_reduce.MOMENTS/MOMENTS_ENT) of ``x``.
 
     Single tiled pass over the tensor, lowered to Mosaic (``interpret=True``
     runs the Pallas interpreter instead, for checks off the chip).
+    ``block_elems`` bounds each grid step's block (``probe_reduce.block_shape``).
     """
-    return _pr.moments_pallas(x, block_rows=block_rows, interpret=interpret,
-                              with_entropy=with_entropy)
-
-
-def _mesh_split(x):
-    """``(mesh, axes)`` when ``x`` is traced under an ambient multi-device
-    mesh (``dist.partition.sharding_ctx``) that no ``shard_map`` binds:
-    ``axes`` are the mesh axes its leading (batch) dim splits over, empty
-    when none divides it.  ``(None, ())`` everywhere else."""
-    from repro.dist import partition
-
-    mesh = partition.current_mesh()
-    if mesh is None or mesh.size == 1 or partition.bound_axes(
-            mesh.axis_names):
-        return None, ()
-    lead = partition.relaxed_pspec(x.shape[:1], ("batch",), mesh)[0]
-    if lead is None:
-        return mesh, ()
-    return mesh, (lead,) if isinstance(lead, str) else tuple(lead)
-
-
-def _sharded_probe_moments(x, mesh, axes, with_entropy: bool):
-    """The kernel on each device's shard of ``x``, combined: sums and
-    counts by ``psum``, ``max_abs`` by ``pmax``, ``numel`` from the global
-    shape.  A Mosaic kernel cannot be partitioned by the compiler, so a
-    sharded jit runs it per shard under ``shard_map``."""
-    from jax.sharding import PartitionSpec
-
-    def per_shard(xs):
-        v = probe_moments(xs, with_entropy=with_entropy)
-        top = jax.lax.pmax(v[_pr.M_MAX_ABS], axes)
-        return jax.lax.psum(v, axes).at[_pr.M_MAX_ABS].set(top)
-
-    v = jax.shard_map(per_shard, mesh=mesh, in_specs=PartitionSpec(axes),
-                      out_specs=PartitionSpec(), check_vma=False)(x)
-    return v.at[_pr.M_NUMEL].set(jnp.float32(x.size))
+    return _pr.moments_pallas(x, block_elems=block_elems,
+                              interpret=interpret, with_entropy=with_entropy)
 
 
 def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
@@ -87,11 +54,11 @@ def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
     for the active event set — the sweep computes nothing outside it (plus
     the free trace-time constants ``numel``/``rows``).
 
-    Policy: the Pallas kernel on TPU for large float tensors — per shard
-    under a sharded jit, where the leading dim splits over the mesh; the
-    fused-jnp fallback for tiny/oddly-shaped/non-float tensors, for tensors
-    a mesh cannot split that way, and on CPU, where interpret-mode Pallas
-    would be a correctness tool, not a fast path.
+    Policy (``use_pallas=None``): the Pallas kernel on TPU for large float
+    tensors; the fused-jnp fallback for tiny/oddly-shaped/non-float tensors
+    and on CPU, where interpret-mode Pallas would be a correctness tool, not
+    a fast path.  A caller tracing a program the compiler will partition
+    passes ``use_pallas=False``: a Mosaic kernel cannot be partitioned.
     """
     if use_pallas is None:
         use_pallas = (
@@ -99,13 +66,9 @@ def tensor_moments(x, names, *, use_pallas: bool | None = None) -> dict:
             and jnp.issubdtype(x.dtype, jnp.floating)
             and x.size >= MIN_PALLAS_MOMENT_NUMEL
         )
-    mesh, axes = _mesh_split(x) if use_pallas else (None, ())
-    if use_pallas and (mesh is None or axes):
+    if use_pallas:
         with_entropy = "ent_sum" in set(names)
-        if mesh is None:
-            vec = probe_moments(x, with_entropy=with_entropy)
-        else:
-            vec = _sharded_probe_moments(x, mesh, axes, with_entropy)
+        vec = probe_moments(x, with_entropy=with_entropy)
         chans = _pr.MOMENTS_ENT if with_entropy else _pr.MOMENTS
         out = dict(zip(chans, vec))
         out.update(_pr.static_channel_values(x.shape))  # exact numel + rows

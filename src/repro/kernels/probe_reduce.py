@@ -18,12 +18,12 @@ monitoring stays lightweight only if counter reads share their passes over
 the data.
 
 Layout: the input is viewed as (lead, rows, cols) — its leading axes
-collapsed, which moves no data — and a 3-D grid walks 2-D (rows, cols)
-tiles; partial moments accumulate into a (1, 8) f32 output block that every
-grid step maps to (revisiting semantics keep it VMEM-resident).  Edge tiles
-may run ragged past the end of the array; out-of-bounds elements are masked
-via their (row, column) index, so non-tile-aligned shapes are exact — and
-never pay a pad copy.  NaNs propagate through sum/sum_sq/sum_abs/max_abs
+collapsed, which moves no data — and a 3-D grid walks it in blocks of about
+``BLOCK_ELEMS`` elements (``block_shape``); partial moments accumulate into
+a (1, 8) f32 output block that every grid step maps to (revisiting
+semantics keep it VMEM-resident).  Edge blocks may run ragged past the end
+of the array; out-of-bounds elements are masked via their index, so
+non-tile-aligned shapes are exact — and never pay a pad copy.  NaNs propagate through sum/sum_sq/sum_abs/max_abs
 exactly as they do through the unfused ``jnp`` reductions, so fused and
 legacy event values agree even on poisoned tensors.
 
@@ -86,35 +86,34 @@ def static_channel_values(shape) -> dict:
     return {"numel": jnp.float32(numel), "rows": jnp.float32(rows)}
 
 
-def _moment_kernel(x_ref, o_ref, *, rows: int, cols: int, block: tuple,
+def _moment_kernel(x_ref, o_ref, *, dims: tuple, block: tuple,
                    with_entropy: bool):
-    """One grid step: fold one (block_rows, block_cols) tile into the
+    """One grid step: fold one (lead, rows, cols) block into the
     accumulator.
 
-    Grid axes are (leading slab, row block, column block).  Tiles on the
-    bottom/right edge may run past the end of the array (ragged tail) —
-    out-of-bounds elements carry unspecified values, so when the block does
-    not tile the array every use of ``x`` is select-masked by the element's
-    (row, column) index before any reduction.
+    Grid axes are (lead block, row block, column block).  Blocks on the
+    far edge of an axis the block does not tile may run past the end of
+    the array (ragged tail) — out-of-bounds elements carry unspecified
+    values, so every use of ``x`` is then select-masked by the element's
+    index along each ragged axis before any reduction.
     """
     import jax.experimental.pallas as pl
 
     n_chan = len(MOMENTS_ENT) if with_entropy else len(MOMENTS)
-    lead, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    br, bc = block
+    pids = [pl.program_id(a) for a in range(3)]
 
-    @pl.when((lead == 0) & (i == 0) & (j == 0))
+    @pl.when((pids[0] == 0) & (pids[1] == 0) & (pids[2] == 0))
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    if rows % br or cols % bc:
-        r = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) + i * br
-        c = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * bc
-        valid = (r < rows) & (c < cols)
-        xm = jnp.where(valid, x, 0.0)  # NaN/Inf survive in valid lanes
-    else:
-        valid, xm = None, x
+    valid = None
+    for axis, (n, b, pid) in enumerate(zip(dims, block, pids)):
+        if n % b:
+            idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) + pid * b
+            valid = idx < n if valid is None else valid & (idx < n)
+    # NaN/Inf survive in valid elements
+    xm = x if valid is None else jnp.where(valid, x, 0.0)
 
     def count(pred):
         if valid is not None:
@@ -146,36 +145,68 @@ def _moment_kernel(x_ref, o_ref, *, rows: int, cols: int, block: tuple,
     o_ref[...] = jnp.where(chan == M_MAX_ABS, new_max, acc + part)
 
 
-# Column tile cap: a multiple of LANES, so a row wider than this is walked
-# in lane-aligned tiles with a masked ragged tail (vocab-wide logits).
-MAX_BLOCK_COLS = 8 * LANES
-# Row tiles are multiples of this: the sublane tile of the narrowest dtype
-# a probe sees (32 rows for 8-bit, 16 for bf16, 8 for f32).
-ROW_ALIGN = 32
+# Default block budget, in elements of the block's VMEM footprint (its two
+# minor dims padded to the (sublane, lane) tile): 1 MiB of f32 per buffer.
+BLOCK_ELEMS = 1 << 18
 
 
-def _block_shape(rows: int, cols: int, block_rows: int) -> tuple[int, int]:
-    """(block_rows, block_cols) legal for Mosaic: each dim is either the
-    full array dim or a multiple of its hardware tile."""
-    bc = cols if cols <= MAX_BLOCK_COLS else MAX_BLOCK_COLS
-    br = max(ROW_ALIGN, block_rows // ROW_ALIGN * ROW_ALIGN)
-    return (rows if rows <= br else br), bc
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def moments_pallas(x, *, block_rows: int = 256, interpret: bool = False,
-                   with_entropy: bool = False):
+def _fit(n: int, limit: int, align: int) -> int:
+    """Block length along a dim of ``n``: ``n`` itself when it fits in
+    ``limit``, else the largest multiple of ``align`` within ``limit``
+    (at least ``align``) — preferring, within a factor of two, one that
+    divides ``n``, since a ragged tail costs a mask on every block."""
+    top = max(align, limit // align * align)
+    if n <= max(limit, top):
+        return n
+    for b in range(top, top // 2, -align):
+        if n % b == 0:
+            return b
+    return top
+
+
+def block_shape(dims: tuple, itemsize: int,
+                block_elems: int = BLOCK_ELEMS) -> tuple[int, int, int]:
+    """(lead, rows, cols) block of a ``dims`` view for the moment kernel.
+
+    Mosaic wants each of the two minor block dims to be either the whole
+    array dim or a multiple of its hardware tile (``LANES`` columns; 8 rows
+    of 32-bit, 16 of 16-bit, 32 of 8-bit data).  Within that, the block
+    takes as much as ``block_elems`` of padded VMEM holds: whole rows when
+    one row tile of them fits (else lane-aligned column tiles, which widen
+    as rows get fewer), then whole slabs of rows, then as many whole slabs
+    along ``lead`` as fit — so a tensor of many small slabs (per-head
+    q/k/v) runs in few grid steps.  A budget below one hardware tile is
+    raised to one tile.
+    """
+    lead, rows, cols = dims
+    sub = max(8, 32 // itemsize)
+    bc = _fit(cols, block_elems // sub, LANES)
+    pbc = _round_up(bc, LANES)
+    br = _fit(rows, block_elems // pbc, sub) if bc == cols else min(rows, sub)
+    if (br, bc) != (rows, cols):
+        return 1, br, bc
+    return _fit(lead, max(1, block_elems // (_round_up(rows, sub) * pbc)),
+                1), br, bc
+
+
+def moments_pallas(x, *, block_elems: int = BLOCK_ELEMS,
+                   interpret: bool = False, with_entropy: bool = False):
     """Raw moment vector f32[8] (f32[9] with entropy) in a single tiled pass.
 
     The input is viewed as ``(lead, rows, cols)`` — every axis before the
     last two collapsed into ``lead``, which is a layout-preserving reshape
     on TPU (its tiled layout covers only the two minor dims), never a copy.
-    The grid walks ``lead`` x row tiles x column tiles; tiles past the end
+    The grid walks that view in ``block_shape`` blocks; blocks past the end
     of a ragged dim are masked in-kernel — no ``jnp.pad``, which would
     re-materialize the whole tensor and double the HBM traffic the kernel
-    exists to remove.  Blocks are 2-D (``lead`` is squeezed), so bf16 and
-    f32 inputs lower alike, and ``jax.vmap`` (the serve engine probes each
-    lane inside one) only prepends a grid axis.  ``with_entropy`` (static,
-    plan-driven) appends the ``ent_sum`` channel to the same sweep.
+    exists to remove.  bf16 and f32 inputs lower alike, and ``jax.vmap``
+    (the serve engine probes each lane inside one) only prepends a grid
+    axis.  ``with_entropy`` (static, plan-driven) appends the ``ent_sum``
+    channel to the same sweep.
     """
     n = int(x.size)
     if n == 0:
@@ -184,16 +215,17 @@ def moments_pallas(x, *, block_rows: int = 256, interpret: bool = False,
     shape = (1, 1) + tuple(x.shape)
     rows, cols = shape[-2], shape[-1]
     x3 = x.reshape(-1, rows, cols)
-    br, bc = _block_shape(rows, cols, block_rows)
-    grid = (x3.shape[0], -(-rows // br), -(-cols // bc))
+    dims = tuple(x3.shape)
+    block = block_shape(dims, jnp.dtype(x.dtype).itemsize, block_elems)
+    grid = tuple(-(-d // b) for d, b in zip(dims, block))
 
     import jax.experimental.pallas as pl
 
     out = pl.pallas_call(
-        functools.partial(_moment_kernel, rows=rows, cols=cols,
-                          block=(br, bc), with_entropy=with_entropy),
+        functools.partial(_moment_kernel, dims=dims, block=block,
+                          with_entropy=with_entropy),
         grid=grid,
-        in_specs=[pl.BlockSpec((None, br, bc), lambda l, i, j: (l, i, j))],
+        in_specs=[pl.BlockSpec(block, lambda l, i, j: (l, i, j))],
         out_specs=pl.BlockSpec((1, n_chan), lambda l, i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n_chan), jnp.float32),
         interpret=interpret,

@@ -18,13 +18,19 @@ Mesh axes:
 from __future__ import annotations
 
 import jax
+import numpy as np
 from jax.sharding import AxisType
 
 
-def auto_mesh(shape, axes):
-    """``jax.make_mesh`` over all local devices, every axis Auto."""
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+def auto_mesh(shape, axes, devices=None):
+    """A mesh with every axis Auto: over all local devices
+    (``jax.make_mesh``), or over ``devices`` in order when given — a
+    subset, or the devices of a described topology."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes,
+                             axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -209,7 +209,8 @@ def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
         # steps of latency
         mstate = mon.sync(mstate, runtime=runtime)
         # the mesh is ambient while the step traces: activations follow the
-        # logical-axis rules and the probe kernel runs per shard
+        # logical-axis rules, and probes take XLA's partitionable reduction
+        # instead of the Mosaic kernel
         with sharding_ctx(mesh) if mesh is not None else \
                 contextlib.nullcontext():
             (tstate, out), mstate = jit_step(mstate, batches, tstate)

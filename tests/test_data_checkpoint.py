@@ -129,8 +129,9 @@ def test_crash_recovery_discovers_latest_valid(tmp_path):
 def test_elastic_restore_into_mesh(tmp_path):
     """Checkpoints restore under any mesh (1-device here) via logical axes."""
     from repro.dist.partition import sharding_ctx
+    from repro.launch.mesh import auto_mesh
 
-    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = auto_mesh((1,), ("data",))
     path = str(tmp_path / "ck.npz")
     tree = {"w": jnp.ones((8, 4))}
     save_tree(path, tree)

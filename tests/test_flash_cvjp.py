@@ -78,10 +78,11 @@ def test_cvjp_block_shape_invariance(cfg):
 def test_run_attention_head_padding_slices_back(cfg):
     """Padded heads (TP divisibility) must not change the result."""
     from repro.dist.partition import sharding_ctx
+    from repro.launch.mesh import auto_mesh
 
     q, k, v = _mk(1, 128, 128, 5, 5, 32)  # 5 heads: never divides 2
     want = ref.attention(q, k, v, causal=True)
-    mesh = jax.make_mesh((1,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = auto_mesh((1,), ("model",))
     with sharding_ctx(mesh):  # tp=1 -> no pad; sanity
         got = L.run_attention(cfg, q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from repro import core as scalpel
 from repro.core.context import EventSpec, MonitorSpec, ScopeContext
 from repro.dist.partition import sharding_ctx
+from repro.launch.mesh import auto_mesh
 
 assert len(jax.devices()) == 2
 
@@ -49,7 +50,7 @@ def work(x):
 
 
 x = jnp.arange(16.0)
-mesh = jax.make_mesh((2,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+mesh = auto_mesh((2,), ("data",))
 
 # ---- shard_map: per-shard collection, in-graph psum --------------------
 mon = scalpel.Monitor(spec)

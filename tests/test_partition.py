@@ -13,11 +13,12 @@ from repro.dist.partition import (
     sharding_ctx,
     tree_shardings,
 )
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.fixture()
 def mesh1():
-    return jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+    return auto_mesh((1,), ("data",))
 
 
 def test_no_context_is_noop():
@@ -41,12 +42,12 @@ def test_pspec_duplicate_mesh_axis_suppressed(mesh1):
 
 
 def test_relaxation_drops_nondividing(mesh1):
-    mesh = jax.make_mesh((1,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = auto_mesh((1,), ("model",))
     rules = {"mlp": ("model",)}
     ps = relaxed_pspec((7,), ("mlp",), mesh, rules)
     assert ps == PartitionSpec("model")  # 1 divides everything
     # model axis absent
-    mesh2 = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+    mesh2 = auto_mesh((1,), ("data",))
     ps2 = relaxed_pspec((7,), ("mlp",), mesh2, rules)
     assert ps2 == PartitionSpec(None)
 

@@ -32,20 +32,28 @@ def test_channel_vocabulary_in_sync():
 # stage 1: the kernel vs the unfused jnp reference
 # ---------------------------------------------------------------------------
 
+# a small block budget (one or two hardware tiles) splits these shapes
+# into several blocks along every axis the comment names
+SMALL_BLOCK = 2048
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("shape", [
     (1024,),          # 1-D, tile-aligned
     (1000,),          # 1-D, non-tile-aligned
-    (64, 129),        # 2-D, ragged lanes
+    (64, 129),        # 2-D, ragged row blocks
     (7, 33, 65),      # 3-D, nothing aligned
     (1, 1),           # degenerate
+    (3, 40, 2500),    # ragged column blocks and row blocks, lead > 1
+    (9, 5, 20),       # several whole slabs per block, ragged lead blocks
 ])
 def test_pallas_moments_match_reference(shape, dtype):
     rng = np.random.default_rng(hash(shape) % 2**32)
     x = rng.normal(size=shape).astype(np.float32)
     x.flat[:: max(1, x.size // 17)] = 0.0  # some exact zeros
     xj = jnp.asarray(x).astype(dtype)
-    got = np.asarray(ops.probe_moments(xj, block_rows=8, interpret=True))
+    got = np.asarray(ops.probe_moments(xj, block_elems=SMALL_BLOCK,
+                                       interpret=True))
     want = np.asarray(pr.moments_ref(xj))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=1e-5)
     # numel is exact (static constant, never a rounded f32 accumulation);
@@ -60,7 +68,8 @@ def test_pallas_entropy_channel_matches_reference(shape):
     rng = np.random.default_rng(11)
     p = jax.nn.softmax(jnp.asarray(rng.normal(size=shape), jnp.float32), -1)
     got = np.asarray(
-        ops.probe_moments(p, block_rows=1, interpret=True, with_entropy=True)
+        ops.probe_moments(p, block_elems=SMALL_BLOCK, interpret=True,
+                          with_entropy=True)
     )
     want = np.asarray(pr.moments_ref(p, with_entropy=True))
     assert got.shape == (len(pr.MOMENTS_ENT),)
@@ -71,11 +80,30 @@ def test_pallas_entropy_channel_matches_reference(shape):
 
 def test_pallas_moments_nan_inf_propagation():
     a = np.array([np.nan, 1.5, np.inf, -np.inf, 0.0] * 64, np.float32)
-    got = np.asarray(ops.probe_moments(jnp.asarray(a), block_rows=1,
-                                       interpret=True))
+    got = np.asarray(ops.probe_moments(jnp.asarray(a), interpret=True))
     want = np.asarray(pr.moments_ref(jnp.asarray(a)))
     np.testing.assert_allclose(got, want, equal_nan=True)
     assert got[pr.M_NAN] == 64 and got[pr.M_INF] == 128
+
+
+@pytest.mark.parametrize("dims,itemsize,budget,want", [
+    ((8, 2048, 768), 2, pr.BLOCK_ELEMS, (1, 256, 768)),   # row blocks
+    ((1024, 8, 128), 2, pr.BLOCK_ELEMS, (128, 8, 128)),   # whole slabs
+    ((1, 1, 151936), 2, pr.BLOCK_ELEMS, (1, 1, 16384)),   # wide row
+    ((1, 1, 151936), 4, pr.BLOCK_ELEMS, (1, 1, 32768)),
+    ((3, 40, 2500), 4, SMALL_BLOCK, (1, 8, 256)),          # col tiles
+    ((9, 5, 20), 4, SMALL_BLOCK, (2, 5, 20)),               # ragged lead
+    ((2, 3, 5), 4, 1, (1, 3, 5)),       # a budget below one tile: one tile
+])
+def test_block_shape_fills_budget_with_legal_tiles(dims, itemsize, budget,
+                                                   want):
+    """Blocks take as much of the budget as the (sublane, lane) tiling
+    allows: few grid steps for many small slabs and for wide rows."""
+    got = pr.block_shape(dims, itemsize, budget)
+    assert got == want
+    sub = 32 // itemsize
+    for n, b, tile in zip(dims[1:], got[1:], (sub, pr.LANES)):
+        assert b == n or b % tile == 0
 
 
 def test_named_moments_jnp_subset_matches_reference():

@@ -6,8 +6,9 @@ Checks:
   * counters are replicated and call counts match the unsharded run,
   * loss matches the single-device run (SPMD correctness),
   * elastic re-mesh: a checkpoint saved under (2,4) restores under (4,2),
-  * the probe kernel runs per data shard under the sharded jit and its
-    all-reduced moments equal the unsharded reference.
+  * under the sharded jit the probe layer takes XLA's reduction (a Mosaic
+    kernel cannot be partitioned) and its counters equal the unsharded
+    run; inside a shard_map the kernel policy is left to choose.
 """
 import json
 import os
@@ -95,22 +96,44 @@ with mesh2, sharding_ctx(mesh2):
         for a, b in zip(jax.tree.leaves(t2.params), jax.tree.leaves(back))
     )
 
-# ---- the probe kernel per shard under a sharded jit ----
-# (interpreted here: a Mosaic kernel cannot be partitioned by the compiler,
-# so tensor_moments runs it on each data shard and all-reduces the moments)
-import functools
-from repro.kernels import ops, probe_reduce as pr
-ops.probe_moments = functools.partial(ops.probe_moments, interpret=True)
+# ---- the probe layer's choice under a partitioned trace ----
+# a Mosaic kernel cannot be partitioned: under a sharded jit the probe
+# layer asks for XLA's reduction (use_pallas=False), inside a shard_map
+# binding every mesh axis it leaves the choice to the kernel policy (None)
+from jax.sharding import PartitionSpec as P
+from repro.core.context import EventSpec, MonitorSpec, ScopeContext
+from repro.kernels import ops as kops
+real_moments, seen = kops.tensor_moments, []
+
+def spy(x, names, *, use_pallas=None):
+    seen.append(use_pallas)
+    return real_moments(x, names, use_pallas=use_pallas)
+
+kops.tensor_moments = spy
+pspec = MonitorSpec.of([ScopeContext.exhaustive(
+    "p", [EventSpec("ACT_RMS", "x"), EventSpec("ACT_MAX_ABS", "x")])])
+
+def body(x):
+    with scalpel.function("p"):
+        scalpel.probe(x=x)
+    return x
+
 xk = jax.random.normal(jax.random.PRNGKey(3), (8, 40, 130))
-xk = xk.at[1, 2, 3].set(0.0)
-names = pr.MOMENTS[:7]
+pmon = scalpel.Monitor(pspec)
+_, m_ref = jax.jit(pmon.wrap(body))(pmon.init(), xk)
+choice_plain = seen[-1]
 with sharding_ctx(mesh):
-    got = jax.jit(lambda t: ops.tensor_moments(t, names, use_pallas=True))(
-        jax.device_put(xk, NamedSharding(mesh, PartitionSpec("data"))))
-want = pr.moments_ref(xk)
-kernel_ok = all(
-    np.allclose(float(got[n]), float(want[i]), rtol=1e-5, atol=1e-3)
-    for i, n in enumerate(pr.MOMENTS))
+    xs = jax.device_put(xk, NamedSharding(mesh, P("data")))
+    _, m_spmd = jax.jit(pmon.wrap(body))(pmon.init(), xs)
+    choice_spmd = seen[-1]
+    smon = scalpel.Monitor(pspec)
+    jax.jit(smon.shard_wrap(body, mesh, in_specs=P("data"),
+                            out_specs=P("data")))(smon.init(), xs)
+    choice_shard = seen[-1]
+kernel_ok = (choice_plain is None and choice_spmd is False
+             and choice_shard is None
+             and np.allclose(np.asarray(m_ref.values),
+                             np.asarray(m_spmd.values), rtol=1e-5))
 
 print(json.dumps({
     "kernel_sharded_ok": bool(kernel_ok),

@@ -311,6 +311,62 @@ def test_background_drain_failure_reraised_on_caller(monkeypatch, surface):
     plane.close()  # reported once: a later close is clean
 
 
+EXIT_SCRIPT = """
+import sys, time
+from repro.core.context import EventSpec, MonitorSpec, ScopeContext
+from repro.core.counters import CounterState
+from repro.core.runtime import ScalpelRuntime
+
+route, broken = sys.argv[1], sys.argv[2] == "broken"
+spec = MonitorSpec.of([ScopeContext.exhaustive("f", [EventSpec("MEAN", "x")])])
+rt = ScalpelRuntime(spec, drain_interval_s=0.005,
+                    report_at_exit=route == "exit_report",
+                    graceful_shutdown=route == "shutdown")
+plane = rt.telemetry
+cs = CounterState.zeros(spec)
+rt.on_step(cs)  # the first append drains on this thread
+if broken:
+    def fail():
+        raise ValueError("transfer failed")
+    plane._drain_once_inner = fail
+    rt.on_step(cs)
+    deadline = time.time() + 5.0
+    while plane._drain_error is None and time.time() < deadline:
+        time.sleep(0.005)
+    assert plane._drain_error is not None
+print("main done", flush=True)
+"""
+
+
+@pytest.mark.parametrize("route,broken", [
+    ("plane", True), ("shutdown", True), ("exit_report", True),
+    ("plane", False),
+], ids=["plane-broken", "shutdown-broken", "exit-report-broken",
+        "plane-clean"])
+def test_drain_failure_at_exit_fails_the_process(route, broken):
+    """A drain error nobody collected before exit is printed at exit and the
+    process exits 1, whichever exit hook meets it first (the plane's own
+    close, the runtime's graceful shutdown, its exit report)."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    # a plain host process: never contends for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run(
+        [sys.executable, "-c", EXIT_SCRIPT, route,
+         "broken" if broken else "clean"],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert "main done" in out.stdout, out.stderr[-3000:]
+    if broken:
+        assert out.returncode == 1, out.stderr[-3000:]
+        assert "transfer failed" in out.stderr
+    else:
+        assert out.returncode == 0, out.stderr[-3000:]
+
+
 def test_jsonl_sink_buffers_and_flushes(tmp_path):
     spec = _spec()
     path = str(tmp_path / "t.jsonl")

@@ -1,6 +1,6 @@
 """The probe kernel compiles for a TPU v5e, where the train and serve paths
 call it: bf16 and f32 activations at real widths, per-lane logits under
-``jax.vmap``, the entropy variant, and the per-shard path of a sharded jit.
+``jax.vmap``, the entropy variant, and a monitored probe on a data mesh.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  The topology is described inside a fixture (never at import), so
@@ -9,12 +9,12 @@ file loads the TPU library.
 """
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.dist.partition import sharding_ctx
 from repro.kernels import ops
+from repro.launch.mesh import auto_mesh
 
 
 @pytest.fixture(scope="module")
@@ -54,9 +54,13 @@ def _compiled_text(fn, *args) -> str:
     ((8, 2048, 768), jnp.bfloat16, False, False),     # xlstm_125m residual
     ((8, 2048, 768), jnp.float32, False, False),
     ((4, 512, 5120), jnp.bfloat16, False, False),     # qwen3_14b prefill
+    ((1, 1024, 8, 128), jnp.bfloat16, False, False),  # per-head k/v slabs
+    ((3, 1000, 777), jnp.bfloat16, False, False),     # ragged row blocks
+    ((1021, 8, 128), jnp.bfloat16, False, False),     # ragged lead blocks
     ((4, 1, 1, 151936), jnp.bfloat16, True, False),   # per-lane decode logits
     ((8, 4, 2048, 2048), jnp.float32, False, True),   # attention probs
-], ids=["bf16-768", "f32-768", "bf16-5120", "vmap-logits", "entropy"])
+], ids=["bf16-768", "f32-768", "bf16-5120", "bf16-kv-slabs", "bf16-ragged-rows",
+        "bf16-ragged-lead", "vmap-logits", "entropy"])
 def test_probe_kernel_compiles_for_v5e(one_chip, shape, dtype, vmapped,
                                        with_entropy):
     def fn(x):
@@ -68,19 +72,37 @@ def test_probe_kernel_compiles_for_v5e(one_chip, shape, dtype, vmapped,
     assert "tpu_custom_call" in _compiled_text(fn, x)
 
 
-def test_probe_kernel_runs_per_shard_under_a_sharded_jit(topo):
-    """A Mosaic kernel cannot be partitioned by the compiler: on a data
-    mesh the probe path runs it per shard and all-reduces the moments."""
-    mesh = jax.sharding.Mesh(
-        np.asarray(topo.devices[:4]).reshape(4, 1), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+def test_probe_kernel_runs_per_shard_under_a_sharded_jit(topo, monkeypatch):
+    """A Mosaic kernel cannot be partitioned by the compiler.  A monitored
+    probe under a sharded jit on a data mesh compiles to XLA's reduction,
+    which the partitioner splits and all-reduces; inside a ``shard_map``
+    (the lane-sharded serve path) each shard runs the kernel."""
+    from repro import core as scalpel
+    from repro.core.context import EventSpec, MonitorSpec, ScopeContext
+
+    # the probe policy as it runs on the chip, where the kernel is the
+    # default for a tensor this large
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    mesh = auto_mesh((4, 1), ("data", "model"), devices=topo.devices[:4])
     x = jax.ShapeDtypeStruct(
         (8, 2048, 768), jnp.bfloat16,
         sharding=NamedSharding(mesh, PartitionSpec("data")))
+    spec = MonitorSpec.of([ScopeContext.exhaustive(
+        "p", [EventSpec("ACT_RMS", "x"), EventSpec("ACT_MAX_ABS", "x")])])
 
-    def fn(x):
-        return ops.tensor_moments(x, ("sum_sq", "max_abs"), use_pallas=True)
+    def body(x):
+        with scalpel.function("p"):
+            scalpel.probe(x=x)
+        return x
 
     with sharding_ctx(mesh):
-        text = _compiled_text(fn, x)
-    assert "tpu_custom_call" in text and "all-reduce" in text
+        mon = scalpel.Monitor(spec)
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), mon.init())
+        spmd = _compiled_text(mon.wrap(body), state, x)
+        per_shard = _compiled_text(
+            mon.shard_wrap(body, mesh, in_specs=PartitionSpec("data"),
+                           out_specs=PartitionSpec("data")), state, x)
+    assert "all-reduce" in spmd and "tpu_custom_call" not in spmd
+    assert "tpu_custom_call" in per_shard
