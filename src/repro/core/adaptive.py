@@ -36,9 +36,10 @@ Three loops close per drained snapshot:
   anomalies by construction; the global step-time detector wakes them
   back to CONFIGURED when the workload misbehaves.
 * **budget** — a proportional controller retunes the global ring cadence
-  to hold the measured monitoring overhead (drain-thread seconds from
-  ``TelemetryPlane.drain_seconds`` against wall time between step stamps)
-  within ``overhead_budget`` of step time.
+  to hold the measured monitoring overhead (the drain's work seconds from
+  ``TelemetryPlane.drain_seconds``, which leave out its wait for the
+  device, against wall time between step stamps) within
+  ``overhead_budget`` of step time.
 
 The step-time and budget loops measure per-DRAIN, normalized by the step
 span: snapshots drained in one batch arrive back-to-back (a K-step
@@ -541,9 +542,10 @@ class AdaptiveController:
         within ``overhead_budget`` of wall time.
 
         Ticks once per closed measurement window (``_interval_tick``):
-        overhead = drain-thread seconds accumulated over the window
-        (``TelemetryPlane.drain_seconds`` deltas), over the window's wall
-        time.
+        overhead = drain work seconds accumulated over the window
+        (``TelemetryPlane.drain_seconds`` deltas: snapshots built and sink
+        emits, not the wait for the ring's producing step), over the
+        window's wall time.
 
         A budget of 1.0 (100% of wall time) or more means "no budget":
         the loop is disabled outright rather than left one measurement

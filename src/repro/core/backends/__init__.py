@@ -6,7 +6,8 @@ the JAX/XLA stack exposes).
                   this package re-exports helpers).
 * ``xla_cost``  — static per-program and per-scope FLOPs / bytes / collective
                   traffic from the compiled artifact (roofline source).
-* ``host_time`` — wall-clock dispatch timing around jitted blocks.
+* ``host_time`` — named series of host wall-clock samples and their
+                  outliers (``fit``'s per-step straggler tripwire).
 * ``host_callback`` — a deliberately perfmon-like backend: an ``io_callback``
                   host round-trip on every scope entry/exit (the breakpoint
                   analogue).  Exists to reproduce the paper's overhead

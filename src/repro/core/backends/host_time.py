@@ -1,17 +1,14 @@
-"""Host wall-clock backend: dispatch timing around jitted blocks.
+"""Host wall-clock backend: named series of wall-clock samples.
 
 The cheapest possible "effect" counter — equivalent to the paper's use of
-UNIX ``time`` for the overhead study, but per named block and feeding the
-runtime's adaptive hooks (straggler detection uses the per-step series).
+UNIX ``time`` for the overhead study, but per named series and feeding the
+runtime's adaptive hooks (``fit``'s straggler tripwire reads the per-step
+series' outliers).
 """
 from __future__ import annotations
 
 import dataclasses
 import statistics
-import time
-from typing import Any, Callable
-
-import jax
 
 
 @dataclasses.dataclass
@@ -28,23 +25,6 @@ class TimingStats:
 class HostTimer:
     def __init__(self):
         self.samples: dict[str, list[float]] = {}
-
-    def wrap(self, fn: Callable, name: str, block: bool = True) -> Callable:
-        """Wrap a (possibly jitted) callable with wall-clock timing.
-
-        ``block=True`` calls ``block_until_ready`` on the outputs so the
-        measurement covers device execution, not just dispatch.
-        """
-
-        def timed(*args, **kwargs):
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            if block:
-                out = jax.block_until_ready(out)
-            self.samples.setdefault(name, []).append(time.perf_counter() - t0)
-            return out
-
-        return timed
 
     def record(self, name: str, seconds: float) -> None:
         self.samples.setdefault(name, []).append(seconds)
@@ -64,9 +44,6 @@ class HostTimer:
             max_s=xs[-1],
         )
 
-    def all_stats(self) -> list[TimingStats]:
-        return [self.stats(k) for k in sorted(self.samples)]
-
     def outliers(self, name: str, sigma: float = 3.0) -> list[int]:
         """Indices of samples more than ``sigma`` stdevs above the median —
         the straggler-detection primitive."""
@@ -76,22 +53,3 @@ class HostTimer:
         med = statistics.median(xs)
         sd = statistics.pstdev(xs) or 1e-12
         return [i for i, x in enumerate(xs) if (x - med) / sd > sigma]
-
-
-def time_compiled(fn: Callable, *args, iters: int = 10, warmup: int = 2,
-                  **kwargs) -> dict[str, Any]:
-    """Benchmark helper: median wall time of a callable over ``iters`` runs."""
-    for _ in range(warmup):
-        jax.block_until_ready(fn(*args, **kwargs))
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args, **kwargs))
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    return {
-        "median_s": ts[len(ts) // 2],
-        "min_s": ts[0],
-        "mean_s": sum(ts) / len(ts),
-        "iters": iters,
-    }

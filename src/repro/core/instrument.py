@@ -504,11 +504,15 @@ def function(name: str):
 
 
 def probe(**tensors) -> None:
-    """Evaluate the current scope's monitoring context on named tensors."""
+    """Evaluate the current scope's monitoring context on named tensors.
+
+    The probe's ops carry ``scalpel.probe`` in their HLO op metadata, so a
+    device trace tells them from the model's."""
     col = current_collector()
     if col is None:
         return
-    col.probe(col.current_scope, tensors)
+    with jax.named_scope("scalpel.probe"):
+        col.probe(col.current_scope, tensors)
 
 
 def probe_scope(name: str, **tensors) -> None:

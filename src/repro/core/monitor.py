@@ -292,6 +292,7 @@ class Monitor:
         axes = partition.counter_reduce_axes(self.counter_axes)
         return delta.psum(axes) if axes else delta
 
+    @jax.named_scope("scalpel.commit")
     def commit(self, mstate: MonitorState, delta: plan_lib.CompactDelta,
                reduce: bool = True) -> MonitorState:
         """Fold a region's compact delta into the state: mesh-reduce,
@@ -354,6 +355,7 @@ class Monitor:
             fingerprint=self.spec.fingerprint,
         )
 
+    @jax.named_scope("scalpel.commit")
     def commit_lanes(self, lstate: LaneMonitorState,
                      delta: plan_lib.CompactDelta,
                      active) -> LaneMonitorState:

@@ -29,7 +29,6 @@ from __future__ import annotations
 import atexit
 import signal
 import threading
-import time
 import traceback
 from typing import Callable
 
@@ -69,7 +68,6 @@ class ScalpelRuntime:
         self.state = CounterState.zeros(spec)
         self.reload_count = 0
         self.last_reload_errors: list[str] = []
-        self._wall: dict[str, float] = {}
 
         self.telemetry = telemetry_lib.TelemetryPlane(
             spec, depth=ring_depth, cadence=max(1, hook_every),
@@ -323,26 +321,6 @@ class ScalpelRuntime:
         return check_plan_metadata(self.spec.fingerprint, meta,
                                    strict=strict)
 
-    # -- host-side wall-clock context (host_time backend feed) --------------
-    def time_block(self, name: str):
-        rt = self
-
-        class _Timer:
-            def __enter__(self_inner):
-                self_inner.t0 = time.perf_counter()
-                return self_inner
-
-            def __exit__(self_inner, *exc):
-                dt = time.perf_counter() - self_inner.t0
-                rt._wall[name] = rt._wall.get(name, 0.0) + dt
-                return False
-
-        return _Timer()
-
-    @property
-    def wall_times(self) -> dict[str, float]:
-        return dict(self._wall)
-
     # -- reporting ----------------------------------------------------------
     def report(self, title: str = "ScALPEL report") -> str:
         text = report_lib.format_text(self.snapshot(), title=title)
@@ -355,6 +333,7 @@ class ScalpelRuntime:
         parts = [
             f"drains={st['drain_count']}",
             f"drain_s={st['drain_seconds']:.3f}",
+            f"drain_wait_s={st['drain_wait_seconds']:.3f}",
             f"dropped_snapshots={st['dropped_snapshots']}",
         ]
         if st["sink_errors"]:

@@ -52,6 +52,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import report as report_lib
 from .context import MonitorSpec
@@ -167,6 +168,7 @@ class SnapshotRing:
         )
 
 
+@jax.named_scope("scalpel.ring_append")
 def ring_append(ring: SnapshotRing, counters,
                 tparams: TelemetryParams, step) -> SnapshotRing:
     """``lax.cond``-guarded ring append — pure device work, jit/scan safe.
@@ -480,9 +482,15 @@ class TelemetryPlane:
         # incremental drain copies only slots newer than the cursor, so at
         # depth ≫ pending this is far below drain_count * depth)
         self.slots_copied = 0
-        # host seconds spent inside _drain_once (transfers + sink emits) —
-        # the adaptive budget loop's measured monitoring overhead
+        # host seconds of drain WORK: snapshots built from transfers already
+        # on the host, plus sink emits — the adaptive budget loop's measured
+        # monitoring overhead (span ``scalpel.drain``)
         self.drain_seconds = 0.0
+        # host seconds the drains spent WAITING: for the ring's head (its
+        # producing step still running on the device) and for the host
+        # copies of pending slots — device time, not monitoring work
+        self.drain_wait_seconds = 0.0
+        self._in_work = False  # a sink's emit re-entering the drain
 
         self._lock = threading.Lock()          # ring ref + counters
         # RLock: a hook/sink may call runtime.report()/flush() from inside
@@ -557,6 +565,7 @@ class TelemetryPlane:
             "cadence": self._cadence,
             "drain_count": self.drain_count,
             "drain_seconds": round(self.drain_seconds, 6),
+            "drain_wait_seconds": round(self.drain_wait_seconds, 6),
             "slots_copied": self.slots_copied,
             "dropped_snapshots": self.dropped_snapshots,
             "dropped_tokens": self.dropped_tokens,
@@ -642,7 +651,8 @@ class TelemetryPlane:
         with self._lock:
             self._tok_ring = ring
 
-    def drain_tokens(self) -> list[tuple[int, int, np.ndarray, np.ndarray]]:
+    def drain_tokens(self, step: int | None = None
+                     ) -> list[tuple[int, int, np.ndarray, np.ndarray]]:
         """Drain pending token-ring slots past the token cursor.
 
         Returns ``(seq, step, toks[n_lanes], live[n_lanes])`` per slot, in
@@ -652,33 +662,40 @@ class TelemetryPlane:
         NEVER device computation (the ROADMAP drain invariant; the np
         materialization blocks only until the producing megastep retires,
         which is the engine's sanctioned request-completion sync point).
+
+        Spans (``step``, the caller's megastep index, rides as their
+        argument): ``scalpel.tokens.wait`` until the head and the host
+        copies are ready, ``scalpel.tokens`` for the copy-out after that.
         """
         with self._lock:
             ring = self._tok_ring
         self.token_drains += 1
         if ring is None:
             return []
-        head = int(jax.device_get(ring.head))
-        if head < self._tok_cursor:
-            # fresh lineage published without make_token_ring()
-            self._tok_cursor = 0
-        if head <= self._tok_cursor:
-            return []
-        depth = ring.depth
-        first = max(self._tok_cursor, head - depth)
-        # tokens are outputs, not samples: an overrun is data loss, so the
-        # engine sizes depth > steps-per-drain; account it loudly anyway
-        self.dropped_tokens += first - self._tok_cursor
-        _start_host_copies((ring.steps, ring.toks, ring.live))
-        steps_h = np.asarray(ring.steps)
-        toks_h = np.asarray(ring.toks)
-        live_h = np.asarray(ring.live)
-        out = []
-        for seq in range(first, head):
-            s = seq % depth
-            out.append((seq, int(steps_h[s]), toks_h[s], live_h[s]))
-        self.tok_slots_copied += depth
-        self._tok_cursor = head
+        args = {} if step is None else {"step": int(step)}
+        with TraceAnnotation("scalpel.tokens.wait", **args):
+            head = int(jax.device_get(ring.head))
+            if head < self._tok_cursor:
+                # fresh lineage published without make_token_ring()
+                self._tok_cursor = 0
+            if head <= self._tok_cursor:
+                return []
+            _start_host_copies((ring.steps, ring.toks, ring.live))
+            steps_h = np.asarray(ring.steps)
+            toks_h = np.asarray(ring.toks)
+            live_h = np.asarray(ring.live)
+        with TraceAnnotation("scalpel.tokens", **args):
+            depth = ring.depth
+            first = max(self._tok_cursor, head - depth)
+            # tokens are outputs, not samples: an overrun is data loss, so
+            # the engine sizes depth > steps-per-drain; account it loudly
+            self.dropped_tokens += first - self._tok_cursor
+            out = []
+            for seq in range(first, head):
+                s = seq % depth
+                out.append((seq, int(steps_h[s]), toks_h[s], live_h[s]))
+            self.tok_slots_copied += depth
+            self._tok_cursor = head
         return out
 
     # -- producer side (step loop; never blocks on device) ----------------
@@ -796,101 +813,125 @@ class TelemetryPlane:
             ) from err
 
     def _drain_once(self) -> list[TelemetrySnapshot]:
-        # time INSIDE the lock: drain_seconds is the budget loop's measured
-        # monitoring overhead, and lock-wait is not work — two threads
-        # racing a drain must not double-count the same wall time
+        # times are taken INSIDE the lock: lock-wait is neither the device's
+        # time nor drain work, and two threads racing a drain must not
+        # double-count the same wall time
         with self._drain_lock:
-            t0 = time.perf_counter()
-            try:
-                return self._drain_once_inner()
-            finally:
-                self.drain_seconds += time.perf_counter() - t0
+            return self._drain_once_inner()
 
     def _drain_once_inner(self) -> list[TelemetrySnapshot]:
-        with self._drain_lock:
-            with self._lock:
-                ring = self._ring
-            if ring is None:
-                return []
-            # Probe the scalar head first: an idle tick (nothing appended
-            # since the last drain) costs one scalar transfer, not a full
-            # depth x CounterState ring copy.
-            head = int(jax.device_get(ring.head))
-            if head < self._drained_head:
-                # a fresh ring lineage was published without make_ring():
-                # its head restarted below our cursor — start a new epoch
-                # rather than silently never draining again.
-                self._drained_head = 0
-                self._prev_state = None
-            if head <= self._drained_head:
-                return []
-            depth = ring.depth
-            first = max(self._drained_head, head - depth)
-            self.dropped_snapshots += first - self._drained_head
-            pending = head - first
-            # Incremental drain, as pure buffer transfers (never device
-            # compute — new device work queues behind in-flight steps and
-            # delays snapshots by the whole dispatch window):
-            #   pending == 1 — the steady state of a drain keeping up with
-            #     the append cadence: copy the O(1) ``last`` mirror alone,
-            #     one slot's worth of bytes no matter how deep the ring is.
-            #   pending > 1 — catching up: copy the stacked ring once; the
-            #     pending slots are the bulk of it anyway.
-            out: list[TelemetrySnapshot] = []
+        # a drain re-entered from a sink's emit is part of that emit: the
+        # outer drain times it as work, and it writes no span of its own
+        outer = not self._in_work
+        t0 = time.perf_counter()
+        try:
+            pending = self._gather()
+        finally:
+            if outer:
+                self.drain_wait_seconds += time.perf_counter() - t0
+        if pending is None:
+            return []
+        if not outer:
+            return self._fan_out(*pending)
+        newest_step = pending[-1][-1][1]
+        self._in_work = True
+        t1 = time.perf_counter()
+        try:
+            with TraceAnnotation("scalpel.drain", step=newest_step):
+                return self._fan_out(*pending)
+        finally:
+            self._in_work = False
+            self.drain_seconds += time.perf_counter() - t1
 
-            def emit(seq: int, step_no: int, state) -> None:
-                prev = self._prev_state
-                delta = state if prev is None else state.sub(prev)
-                snap = TelemetrySnapshot(
-                    step=step_no, seq=seq, state=state, delta=delta,
-                    spec=self.spec,
-                )
-                self._prev_state = state
-                self._last_step = snap.step
-                out.append(snap)
+    def _gather(self):
+        """The drain's wait: probe the ring's head and bring the pending
+        slots to the host.  ``None`` when nothing is pending, else
+        ``(head, first, slots copied, [(seq, step, host state), ...])``."""
+        with self._lock:
+            ring = self._ring
+        if ring is None:
+            return None
+        # Probe the scalar head first: an idle tick (nothing appended
+        # since the last drain) costs one scalar transfer, not a full
+        # depth x CounterState ring copy.
+        head = int(jax.device_get(ring.head))
+        if head < self._drained_head:
+            # a fresh ring lineage was published without make_ring():
+            # its head restarted below our cursor — start a new epoch
+            # rather than silently never draining again.
+            self._drained_head = 0
+            self._prev_state = None
+        if head <= self._drained_head:
+            return None
+        depth = ring.depth
+        first = max(self._drained_head, head - depth)
+        # Incremental drain, as pure buffer transfers (never device
+        # compute — new device work queues behind in-flight steps and
+        # delays snapshots by the whole dispatch window):
+        #   pending == 1 — the steady state of a drain keeping up with
+        #     the append cadence: copy the O(1) ``last`` mirror alone,
+        #     one slot's worth of bytes no matter how deep the ring is.
+        #   pending > 1 — catching up: copy the stacked ring once; the
+        #     pending slots are the bulk of it anyway.
+        # Non-blocking device→host: start the copies, then gather on
+        # THIS (drain) thread — the step loop never waits.
+        if head - first == 1:
+            _start_host_copies((ring.last, ring.last_step))
+            state = jax.tree.map(np.asarray, ring.last)
+            return head, first, 1, [
+                (head - 1, int(np.asarray(ring.last_step)), state)]
+        _start_host_copies((ring.steps, ring.calls, ring.values,
+                            ring.samples))
+        steps_h = np.asarray(ring.steps)
+        calls_h = np.asarray(ring.calls)
+        values_h = np.asarray(ring.values)
+        samples_h = np.asarray(ring.samples)
+        mk = type(ring.last)  # ring template: padded or compact
+        slots = []
+        for seq in range(first, head):
+            s = seq % depth  # host-side slicing of the host copy
+            slots.append((seq, int(steps_h[s]),
+                          mk(calls=calls_h[s], values=values_h[s],
+                             samples=samples_h[s])))
+        return head, first, depth, slots
 
-            # Non-blocking device→host: start the copies, then gather on
-            # THIS (drain) thread — the step loop never waits.
-            if pending == 1:
-                _start_host_copies((ring.last, ring.last_step))
-                state = jax.tree.map(np.asarray, ring.last)
-                emit(head - 1, int(np.asarray(ring.last_step)), state)
-                self.slots_copied += 1
-            else:
-                _start_host_copies((ring.steps, ring.calls, ring.values,
-                                    ring.samples))
-                steps_h = np.asarray(ring.steps)
-                calls_h = np.asarray(ring.calls)
-                values_h = np.asarray(ring.values)
-                samples_h = np.asarray(ring.samples)
-                mk = type(ring.last)  # ring template: padded or compact
-                for seq in range(first, head):
-                    s = seq % depth  # host-side slicing of the host copy
-                    state = mk(calls=calls_h[s], values=values_h[s],
-                               samples=samples_h[s])
-                    emit(seq, int(steps_h[s]), state)
-                self.slots_copied += depth
-            self._drained_head = head
-            self.drain_count += 1
-            # hardened fan-out: a raising sink never kills the drain loop —
-            # its failure is recorded, it backs off exponentially (in
-            # drains), and after max_sink_failures consecutive failures it
-            # is dropped; healthy sinks are untouched either way.
-            for s in list(self.sinks):
-                rec = self._sink_records.get(id(s))
-                if rec is None:     # registered behind add_sink's back
-                    self._sink_seq += 1
-                    rec = _SinkRecord(
-                        name=f"{type(s).__name__}#{self._sink_seq}")
-                    self._sink_records[id(s)] = rec
-                if rec.retry_at > self.drain_count:
-                    continue        # backing off
-                for snap in out:
-                    try:
-                        s.emit(snap)
-                        rec.consecutive = 0
-                        rec.retry_at = 0
-                    except Exception:
-                        self._sink_failed(s, rec)
-                        break       # this drain is over for this sink
-            return out
+    def _fan_out(self, head: int, first: int, copied: int,
+                 slots: list) -> list[TelemetrySnapshot]:
+        """The drain's work: delta-decode the host slots into snapshots,
+        advance the cursor, emit to every sink."""
+        self.dropped_snapshots += first - self._drained_head
+        out: list[TelemetrySnapshot] = []
+        for seq, step_no, state in slots:
+            prev = self._prev_state
+            delta = state if prev is None else state.sub(prev)
+            out.append(TelemetrySnapshot(
+                step=step_no, seq=seq, state=state, delta=delta,
+                spec=self.spec,
+            ))
+            self._prev_state = state
+            self._last_step = step_no
+        self.slots_copied += copied
+        self._drained_head = head
+        self.drain_count += 1
+        # hardened fan-out: a raising sink never kills the drain loop —
+        # its failure is recorded, it backs off exponentially (in
+        # drains), and after max_sink_failures consecutive failures it
+        # is dropped; healthy sinks are untouched either way.
+        for s in list(self.sinks):
+            rec = self._sink_records.get(id(s))
+            if rec is None:     # registered behind add_sink's back
+                self._sink_seq += 1
+                rec = _SinkRecord(
+                    name=f"{type(s).__name__}#{self._sink_seq}")
+                self._sink_records[id(s)] = rec
+            if rec.retry_at > self.drain_count:
+                continue        # backing off
+            for snap in out:
+                try:
+                    s.emit(snap)
+                    rec.consecutive = 0
+                    rec.retry_at = 0
+                except Exception:
+                    self._sink_failed(s, rec)
+                    break       # this drain is over for this sink
+        return out

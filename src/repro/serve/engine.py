@@ -34,6 +34,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import core as scalpel
 from repro.models.registry import Arch
@@ -369,37 +370,43 @@ class ContinuousEngine:
             else int(max_new)
         return self.sched.submit(tokens, max_new, seed)
 
-    def _admit_ready(self) -> None:
+    def _admit_ready(self, mega: int) -> None:
         for lane in self.sched.free_lanes():
             if not self.sched.queue:
                 break
             req = self.sched.queue.popleft()
-            if req.seed is not None:
-                key = jax.random.PRNGKey(req.seed)
-            else:
-                self._rng, key = jax.random.split(self._rng)
-            # two async dispatches per admission: monitored prefill (+
-            # first-token sample with the UNSPLIT request key — the serial
-            # contract) and the slab/counter-row write
             s = int(np.shape(req.tokens)[1])
-            width = self.sched.route(s)
-            if self._buckets is not None:
-                toks = np.asarray(req.tokens)
-                if width > s:
-                    toks = np.pad(toks, ((0, 0), (0, width - s)))
-                cache, tok0, pdelta = self.driver.prefill_bucketed(
-                    self.params, self.lstate.params, toks, s, key)
-            else:
-                cache, tok0, pdelta = self.driver.prefill(
-                    self.params, self.lstate.params, req.tokens, key)
-            self._check_traces()
-            (self.slab, self.tok, self.keys, self.active,
-             self.remaining), self.lstate = self.driver.admit(
-                self.lstate, self.slab, self.tok, self.keys, self.active,
-                self.remaining, lane, cache, tok0, key, req.max_new, pdelta)
-            self.sched.admit(lane, req)
-            self.stats["prefills"] += 1
-            self.stats["admissions"] += 1
+            # one span per admission: route, pad and both dispatches
+            with TraceAnnotation("scalpel.serve.admit", step=mega,
+                                 rid=req.rid, width=self.sched.width(s)):
+                self._admit(lane, req, s)
+
+    def _admit(self, lane: int, req, s: int) -> None:
+        if req.seed is not None:
+            key = jax.random.PRNGKey(req.seed)
+        else:
+            self._rng, key = jax.random.split(self._rng)
+        # two async dispatches per admission: monitored prefill (+
+        # first-token sample with the UNSPLIT request key — the serial
+        # contract) and the slab/counter-row write
+        width = self.sched.route(s)
+        if self._buckets is not None:
+            toks = np.asarray(req.tokens)
+            if width > s:
+                toks = np.pad(toks, ((0, 0), (0, width - s)))
+            cache, tok0, pdelta = self.driver.prefill_bucketed(
+                self.params, self.lstate.params, toks, s, key)
+        else:
+            cache, tok0, pdelta = self.driver.prefill(
+                self.params, self.lstate.params, req.tokens, key)
+        self._check_traces()
+        (self.slab, self.tok, self.keys, self.active,
+         self.remaining), self.lstate = self.driver.admit(
+            self.lstate, self.slab, self.tok, self.keys, self.active,
+            self.remaining, lane, cache, tok0, key, req.max_new, pdelta)
+        self.sched.admit(lane, req)
+        self.stats["prefills"] += 1
+        self.stats["admissions"] += 1
 
     def _check_traces(self) -> None:
         """One-shot compile-churn warning: when prefill has traced more
@@ -425,41 +432,61 @@ class ContinuousEngine:
                 f"compiles ({hint}).", RuntimeWarning, stacklevel=3)
 
     def run(self) -> dict[int, ServeResult]:
-        """Drive megasteps until every submitted request completes."""
+        """Drive megasteps until every submitted request completes.
+
+        Host spans (``jax.profiler.TraceAnnotation``, on the device trace's
+        clock), one per leaf phase of a megastep, none inside another, each
+        carrying the megastep index as ``step``; ``drain_tokens`` writes its
+        own ``scalpel.tokens.wait`` and ``scalpel.tokens``."""
         plane = self.runtime.telemetry
         k = self.cfg.steps_per_commit
         t0 = time.perf_counter()
         while True:
+            mega = self.stats["megasteps"]
             # knob swaps (adaptive/runtime) land here — megastep boundary
-            self.lstate = self.mon.sync(self.lstate, runtime=self.runtime)
-            self._admit_ready()
+            with TraceAnnotation("scalpel.serve.sync", step=mega):
+                self.lstate = self.mon.sync(self.lstate,
+                                            runtime=self.runtime)
+            self._admit_ready(mega)
             if not self.sched.occupied:
                 break
-            (self.slab, self.tok, self.keys, self.active, self.remaining), \
-                self.lstate, self.tok_ring = self.driver.megastep(
-                    self.lstate, self.params, self.slab, self.tok,
-                    self.keys, self.active, self.remaining, self.tok_ring)
+            with TraceAnnotation("scalpel.serve.dispatch", step=mega):
+                (self.slab, self.tok, self.keys, self.active,
+                 self.remaining), self.lstate, self.tok_ring = \
+                    self.driver.megastep(
+                        self.lstate, self.params, self.slab, self.tok,
+                        self.keys, self.active, self.remaining,
+                        self.tok_ring)
             self.stats["megasteps"] += 1
-            # arithmetic completion: each occupied lane advanced by
-            # min(K, remaining) tokens — no device readback to retire
-            for lane, rid in self.sched.advance(k):
-                # harvest per-request counters as eager device slices
-                # (async); materialized at join
-                self.sched.set_counters(rid,
-                                        self.lstate.lane_counters(lane))
-            # async monitoring egress: aggregate ring to the drain thread
-            self.runtime.on_step(self.lstate.counters,
-                                 ring=self.lstate.ring)
+            with TraceAnnotation("scalpel.serve.advance", step=mega):
+                # arithmetic completion: each occupied lane advanced by
+                # min(K, remaining) tokens — no device readback to retire
+                for lane, rid in self.sched.advance(k):
+                    # harvest per-request counters as eager device slices
+                    # (async); materialized at join
+                    self.sched.set_counters(
+                        rid, self.lstate.lane_counters(lane))
+            with TraceAnnotation("scalpel.serve.publish", step=mega):
+                # async monitoring egress: aggregate ring to the drain
+                # thread
+                self.runtime.on_step(self.lstate.counters,
+                                     ring=self.lstate.ring)
             # pipelined token drain: consume the PREVIOUS megastep's ring
-            # (its producer already retired) before publishing this one
-            self.stats["tokens_out"] += self.sched.attribute(
-                plane.drain_tokens())
-            self.stats["token_drains"] += 1
-            plane.publish_tokens(self.tok_ring)
+            # before publishing this one.  On a TPU its head reaches the
+            # host only after the megastep just dispatched has left its
+            # decode loop, so this is where the loop waits for the device
+            # (``scalpel.tokens.wait``).
+            drained = plane.drain_tokens(step=mega)
+            with TraceAnnotation("scalpel.serve.attribute", step=mega):
+                self.stats["tokens_out"] += self.sched.attribute(drained)
+                self.stats["token_drains"] += 1
+            with TraceAnnotation("scalpel.serve.publish", step=mega):
+                plane.publish_tokens(self.tok_ring)
         # the one blocking readback: the final ring drain at completion
-        self.stats["tokens_out"] += self.sched.attribute(
-            plane.drain_tokens())
-        self.stats["token_drains"] += 1
+        drained = plane.drain_tokens(step=mega)
+        with TraceAnnotation("scalpel.serve.attribute", step=mega):
+            self.stats["tokens_out"] += self.sched.attribute(drained)
+            self.stats["token_drains"] += 1
         self.stats["wall_s"] += time.perf_counter() - t0
         if plane.dropped_tokens:
             raise RuntimeError(

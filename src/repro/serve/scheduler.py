@@ -87,19 +87,22 @@ class Scheduler:
         return rid
 
     # -- prompt-length bucketing -------------------------------------------
+    def width(self, length: int) -> int:
+        """The pad width of a prompt length: the smallest configured bucket
+        that fits (prompts past the largest bucket — and every prompt when
+        bucketing is off — go exact-length)."""
+        length = int(length)
+        for b in self.buckets or ():
+            if b >= length:
+                return b
+        return length
+
     def route(self, length: int) -> int:
-        """Route a prompt length to its pad width: the smallest configured
-        bucket that fits (prompts past the largest bucket — and every
-        prompt when bucketing is off — go exact-length).  Records pad
+        """Route a prompt length to its pad width (``width``).  Records pad
         waste: the fraction of prefill FLOPs spent on pad is the price of
         the bounded trace count."""
         length = int(length)
-        width = length
-        if self.buckets:
-            for b in self.buckets:
-                if b >= length:
-                    width = b
-                    break
+        width = self.width(length)
         self.prompt_tokens += length
         self.pad_tokens += width - length
         self.buckets_used.add(width)

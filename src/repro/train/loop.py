@@ -19,6 +19,7 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import core as scalpel
 from repro.checkpoint import CheckpointManager
@@ -181,37 +182,50 @@ def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
 
     K = max(1, loop_cfg.steps_per_commit)
 
+    # Host spans (``jax.profiler.TraceAnnotation``, read back from a
+    # profile on the device trace's clock): one per leaf phase of a
+    # megastep, never one inside another on the same thread, each carrying
+    # the megastep index as ``step``.
     def megabatches():
         """Host batches grouped into K-step leading-axis stacks (the final
-        chunk may be ragged — a shorter stack traces once per distinct K)."""
-        buf: list = []
-        first = start_step
-        for s in range(start_step, loop_cfg.steps):
-            buf.append(data.batch_at(s))
-            if len(buf) == K or s == loop_cfg.steps - 1:
-                yield first, s, jax.tree.map(
-                    lambda *xs: np.stack(xs), *buf)
-                buf, first = [], s + 1
+        chunk may be ragged — a shorter stack traces once per distinct K).
+        Runs on the prefetch thread (span ``scalpel.train.build``)."""
+        for first in range(start_step, loop_cfg.steps, K):
+            last = min(first + K, loop_cfg.steps) - 1
+            with TraceAnnotation("scalpel.train.build", step=first // K):
+                stacked = jax.tree.map(
+                    lambda *xs: np.stack(xs),
+                    *[data.batch_at(s) for s in range(first, last + 1)])
+            yield first, last, stacked
 
     it = prefetch(megabatches(), 2)
-    for first_step, last_step, host_batches in it:
+    mega = start_step // K
+    while True:
+        with TraceAnnotation("scalpel.train.batch", step=mega):
+            nxt = next(it, None)
+        if nxt is None:
+            break
+        first_step, last_step, host_batches = nxt
         k_actual = last_step - first_step + 1
         # the per-step batch axis now sits under the stacked step axis
-        batches = shard_batch(
-            host_batches, mesh,
-            axes={name: (None, "batch") + (None,) * (np.ndim(v) - 2)
-                  for name, v in host_batches.items()},
-        )
+        with TraceAnnotation("scalpel.train.put", step=mega):
+            batches = shard_batch(
+                host_batches, mesh,
+                axes={name: (None, "batch") + (None,) * (np.ndim(v) - 2)
+                      for name, v in host_batches.items()},
+            )
         t0 = time.perf_counter()
         # refresh the dynamic knobs riding in the state (mask/period/cadence
         # — reference swaps, never a re-trace); swaps take effect at the
         # NEXT megastep boundary, so the adaptive loop reacts with up to K
         # steps of latency
-        mstate = mon.sync(mstate, runtime=runtime)
+        with TraceAnnotation("scalpel.train.sync", step=mega):
+            mstate = mon.sync(mstate, runtime=runtime)
         # the mesh is ambient while the step traces: activations follow the
         # logical-axis rules, and probes take XLA's partitionable reduction
         # instead of the Mosaic kernel
-        with sharding_ctx(mesh) if mesh is not None else \
+        with TraceAnnotation("scalpel.train.dispatch", step=mega), \
+                sharding_ctx(mesh) if mesh is not None else \
                 contextlib.nullcontext():
             (tstate, out), mstate = jit_step(mstate, batches, tstate)
         inflight.append((last_step, out))
@@ -219,34 +233,40 @@ def fit(arch: Arch, opt_cfg: OptConfig, data_cfg: DataConfig,
         # is synchronized, so device and host overlap up to max_in_flight
         # megasteps (amortized, the recorded time still equals the true
         # per-step time).
-        retire(max_in_flight - 1)
-        runtime.on_step(mstate.counters, ring=mstate.ring)
-        # recorded PER STEP (megastep wall / K): straggler baselines and
-        # step_stats survive a steps_per_commit swap
-        timer.record("train_step",
-                     (time.perf_counter() - t0) / k_actual)
-        if loop_cfg.log_every and last_logged and any(
-                s % loop_cfg.log_every == 0
-                for s in range(first_step, last_step + 1)):
-            # metrics belong to the most recently RETIRED megastep (the
-            # window lags dispatch) — label them with its last step
-            print(f"step {last_logged['step']:5d} "
-                  f"loss {last_logged['loss']:.4f} "
-                  f"gnorm {last_logged['gnorm']:.3f} "
-                  f"lr {last_logged['lr']:.2e} "
-                  f"dt {timer.stats('train_step').mean_s*1e3:.1f}ms "
-                  f"(dispatched {last_step}, window {len(inflight)})")
+        with TraceAnnotation("scalpel.train.retire", step=mega):
+            retire(max_in_flight - 1)
+        with TraceAnnotation("scalpel.train.publish", step=mega):
+            runtime.on_step(mstate.counters, ring=mstate.ring)
+            # recorded PER STEP (megastep wall / K): straggler baselines
+            # and step_stats survive a steps_per_commit swap
+            timer.record("train_step",
+                         (time.perf_counter() - t0) / k_actual)
+            if loop_cfg.log_every and last_logged and any(
+                    s % loop_cfg.log_every == 0
+                    for s in range(first_step, last_step + 1)):
+                # metrics belong to the most recently RETIRED megastep (the
+                # window lags dispatch) — label them with its last step
+                print(f"step {last_logged['step']:5d} "
+                      f"loss {last_logged['loss']:.4f} "
+                      f"gnorm {last_logged['gnorm']:.3f} "
+                      f"lr {last_logged['lr']:.2e} "
+                      f"dt {timer.stats('train_step').mean_s*1e3:.1f}ms "
+                      f"(dispatched {last_step}, window {len(inflight)})")
         if mgr is not None and loop_cfg.ckpt_every and \
                 (last_step + 1) // loop_cfg.ckpt_every \
                 > first_step // loop_cfg.ckpt_every:
             # the cadence can only fire on megastep boundaries; save the
             # state that exists — after last_step+1 steps
-            retire(0)
-            mgr.save(last_step + 1,
-                     {"model": tstate,
-                      "monitor": mon.checkpoint_payload(mstate)},
-                     extra=runtime.save_metadata())
-    retire(0)
+            with TraceAnnotation("scalpel.train.retire", step=mega):
+                retire(0)
+            with TraceAnnotation("scalpel.train.ckpt", step=mega):
+                mgr.save(last_step + 1,
+                         {"model": tstate,
+                          "monitor": mon.checkpoint_payload(mstate)},
+                         extra=runtime.save_metadata())
+        mega += 1
+    with TraceAnnotation("scalpel.train.retire", step=mega):
+        retire(0)
     if mgr is not None:
         mgr.save(loop_cfg.steps,
                  {"model": tstate,

@@ -201,13 +201,3 @@ def test_runtime_unsatisfiable_config_reported(tmp_path):
     )
     rt = scalpel.ScalpelRuntime(spec, config_path=str(cfgp))
     assert rt.last_reload_errors == ["scope:nope"]
-
-
-def test_time_block_accumulates():
-    spec = _spec()
-    rt = scalpel.ScalpelRuntime(spec)
-    with rt.time_block("io"):
-        pass
-    with rt.time_block("io"):
-        pass
-    assert rt.wall_times["io"] >= 0.0
