@@ -360,6 +360,10 @@ def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
     softmax all-reduces); ``pos`` scalar int32 — write position of the new
     token (uniform across the batch, standard static-batch serving).
     Returns (y [b,1,d], cache_k', cache_v').
+
+    Grouped-query form: the query heads are grouped by the KV head they
+    share and contracted against the cache as stored, so the cache is never
+    repeated to the query heads (decode is bound by its KV reads).
     """
     with scalpel.function("attn"):
         b = x.shape[0]
@@ -373,19 +377,20 @@ def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
         )
         cache_k = shard(cache_k, "batch", "kv_seq", None, None)
         cache_v = shard(cache_v, "batch", "kv_seq", None, None)
-        n_rep = cfg.n_heads // cfg.n_kv_heads
-        kr = _repeat_kv(cache_k.astype(x.dtype), n_rep)  # [b,S,h,hd]
-        vr = _repeat_kv(cache_v.astype(x.dtype), n_rep)
-        scale = 1.0 / math.sqrt(q.shape[-1])
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kr).astype(jnp.float32) * scale
-        S = cache_k.shape[1]
-        kpos = jnp.arange(S)[None, None, None, :]
+        _, _, h, hd = q.shape
+        S, kv = cache_k.shape[1], cache_k.shape[2]
+        qg = q.reshape(b, 1, kv, h // kv, hd)  # head g*(h/kv)+r -> (g, r)
+        scale = 1.0 / math.sqrt(hd)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, cache_k.astype(x.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        kpos = jnp.arange(S)
         valid = kpos <= pos
         if cfg.sliding_window:
             valid = valid & (kpos > pos - cfg.sliding_window)
         s = jnp.where(valid, s, -1e30)
         p_attn = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p_attn.astype(x.dtype), vr)
+        out = jnp.einsum("bgrqk,bkgd->bqgrd", p_attn.astype(x.dtype),
+                         cache_v.astype(x.dtype)).reshape(b, 1, h, hd)
         y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
         if cfg.use_bias:
             y = y + p["bo"].astype(x.dtype)
