@@ -110,11 +110,12 @@ def matmul_cost(schedule: str, m: int, n: int, k: int, *, bm: int = 256,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "block_q", "block_kv", "interpret"),
+    static_argnames=("causal", "window", "block_q", "block_kv", "scale",
+                     "interpret"),
 )
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_kv: int = 1024,
-                    interpret: bool = False):
+                    scale: float | None = None, interpret: bool = False):
     """Model-layer convention: q [b,sq,h,d]; k,v [b,sk,kvh,d] (GQA ok)."""
     b, sq, h, d = q.shape
     sk, kvh = k.shape[1], k.shape[2]
@@ -127,7 +128,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     out = _fa.flash_attention_bhsd(
         qf, kf, vf, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=interpret,
+        block_q=block_q, block_kv=block_kv, scale=scale, interpret=interpret,
     )
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 

@@ -1,17 +1,38 @@
-"""Zamba2-style hybrid (family 'hybrid'): Mamba2 backbone + one *shared*
-attention+MLP block applied every ``hybrid.attn_every`` layers.
+"""Zamba2 hybrid (family 'hybrid'): a Mamba2 backbone with shared
+attention+MLP blocks, as published for Zamba2-7B (arXiv:2411.15242;
+https://huggingface.co/Zyphra/Zamba2-7B-Instruct/blob/main/config.json).
 
-Structure: ``n_sites = n_layers // attn_every`` groups, each = attn_every
-Mamba2 layers followed by one application of the shared block; remaining
-``n_layers % attn_every`` Mamba2 layers trail at the end.  The shared block
-operates at 2*d_model on concat(hidden, original_embedding) (Zamba2's
-global-skip concat) and projects back to d_model.
+Every layer runs one Mamba2 mixer, ``x <- x + mamba2(rms_norm(x + t))``,
+where ``t`` is 0 except at the layers named in ``hybrid.hybrid_layer_ids``
+("sites").  The k-th site runs shared block ``k % num_mem_blocks`` (A, B,
+A, B, ... for two blocks) on the hidden state ``x`` and the embedding
+output ``x0`` (in decode, the current token's embedding):
 
-Sub-quadratic backbone -> runs long_500k; the shared block's KV caches (one
-per application site) are sequence-sharded in decode.
+    h = rms_norm(concat(x, x0))                   # 2 * d_model wide
+    a = attn(h)      # q, k, v: 2d -> heads x head_dim; RoPE; o: -> d
+    m = mlp(rms_norm(a))  # gate_up + the site's rank-r adapter, gelu * up
+    t = linear_site(m)                            # d -> d, one per site
+
+The block's output is added to the input of that layer's Mamba2 (before
+its norm), not to the residual.  Attention scores are scaled by
+``(head_dim / 2) ** -0.5``, as Zamba2's attention (which runs at twice the
+model width) does.
+
+The layers are kept as runs: run 0 from layer 0 up to the first site, each
+later run from a site up to the next.  A run's Mamba2 parameters (and, in
+serving, its SSD and conv states) are stacked and scanned; each block,
+site and site KV cache is its own leaf, so no program slices a weight or a
+cache out of a larger one.
+
+A site's KV cache is stored ``[batch, seq, kv_heads, head_dim]``, as the
+dense decoder's, and decode attends through ``layers.decode_attention``.
+
+Sub-quadratic backbone -> runs long_500k; the sites' KV caches are
+sequence-sharded in decode (``cache_axes``).
 """
 from __future__ import annotations
 
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -23,134 +44,173 @@ from . import ssm
 from .params import P, stacked
 from .spec import ModelConfig
 
+# bucketed serving: positions past a traced ``length`` take dt = 0 in every
+# Mamba2 layer (the SSD state passes through them unchanged), the conv state
+# is sliced at ``length``, attention is causal, and decode overwrites the
+# sites' pad KV slots one per step and masks everything past ``pos``
+SUPPORTS_PREFILL_LENGTH = True
 
-def _geometry(cfg: ModelConfig):
-    every = cfg.hybrid.attn_every
-    n_sites = cfg.n_layers // every
-    trailing = cfg.n_layers - n_sites * every
-    return every, n_sites, trailing
-
-
-def _shared_cfg(cfg: ModelConfig) -> ModelConfig:
-    """The shared block's attention runs at 2*d_model."""
-    return cfg.replace(
-        name=cfg.name + "-shared",
-        d_model=2 * cfg.d_model,
-        head_dim=2 * cfg.d_model // cfg.n_heads,
-        family="dense",
-    )
+NORM_EPS = ssm.NORM_EPS
 
 
-def shared_block_specs(cfg: ModelConfig) -> dict:
-    scfg = _shared_cfg(cfg)
-    d2 = scfg.d_model
+def runs(cfg: ModelConfig) -> list[tuple[int, int, int | None]]:
+    """(first layer, end, site index or None) of each run of layers."""
+    ids = cfg.hybrid.hybrid_layer_ids
+    if list(ids) != sorted(set(ids)) or (ids and not
+                                         0 <= ids[0] <= ids[-1] < cfg.n_layers):
+        raise ValueError(f"hybrid_layer_ids {ids} must increase within "
+                         f"[0, {cfg.n_layers})")
+    bounds = sorted({0, *ids, cfg.n_layers})
+    return [(a, b, ids.index(a) if a in ids else None)
+            for a, b in zip(bounds[:-1], bounds[1:])]
+
+
+def attn_cfg(cfg: ModelConfig) -> ModelConfig:
+    """The shared blocks' attention reads concat(x, x0), 2 * d_model wide."""
+    return cfg.replace(name=cfg.name + "-shared", d_model=2 * cfg.d_model,
+                       family="dense")
+
+
+def attn_scale(cfg: ModelConfig) -> float:
+    return (attn_cfg(cfg).resolved_head_dim / 2) ** -0.5
+
+
+def block_specs(cfg: ModelConfig) -> dict:
+    acfg = attn_cfg(cfg)
+    d, f = cfg.d_model, cfg.d_ff
+    h, kv, hd = acfg.n_heads, acfg.n_kv_heads, acfg.resolved_head_dim
     return {
-        "ln1": L.rms_norm_spec(d2),
-        "attn": L.attention_specs(scfg),
-        "ln2": L.rms_norm_spec(d2),
-        "mlp": L.mlp_specs(scfg, cfg.d_ff),
-        "down": P((d2, cfg.d_model), ("heads", "embed")),
+        "ln_attn": L.rms_norm_spec(2 * d),
+        "attn": {
+            "wq": P((2 * d, h, hd), ("embed", "heads", "head_dim")),
+            "wk": P((2 * d, kv, hd), ("embed", "kv_heads", "head_dim")),
+            "wv": P((2 * d, kv, hd), ("embed", "kv_heads", "head_dim")),
+            "wo": P((h, hd, d), ("heads", "head_dim", "embed")),
+        },
+        "ln_mlp": L.rms_norm_spec(d),
+        "gate_up": P((d, 2 * f), ("embed", "mlp")),
+        "down": P((f, d), ("mlp", "embed")),
+    }
+
+
+def site_specs(cfg: ModelConfig) -> dict:
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.hybrid.adapter_rank
+    return {
+        "adapter_in": P((d, r), ("embed", None)),
+        "adapter_out": P((r, 2 * f), (None, "mlp")),
+        "linear": P((d, d), (None, "embed")),
     }
 
 
 def specs(cfg: ModelConfig) -> dict:
-    every, n_sites, trailing = _geometry(cfg)
-    sp = {
+    def run(n):
+        mix = ssm.mamba2_specs(cfg)
+        # in_proj is stored [out, in]: it keeps the scale that the stacked
+        # [in, out] form takes by the default fan-in, 1/sqrt(n * d_model)
+        mix["in_proj"] = dataclasses.replace(
+            mix["in_proj"], scale=(n * cfg.d_model) ** -0.5)
+        return stacked(lambda: {"ln": L.rms_norm_spec(cfg.d_model),
+                                "mix": mix}, n)
+
+    return {
         "embed": L.embed_specs(cfg),
-        "groups": stacked(
-            lambda: {
-                "mamba": stacked(
-                    lambda: {
-                        "ln": L.rms_norm_spec(cfg.d_model),
-                        "mix": ssm.mamba2_specs(cfg),
-                    },
-                    every,
-                )
-            },
-            n_sites,
-        ),
-        "shared": shared_block_specs(cfg),
+        "mamba": [run(b - a) for a, b, _ in runs(cfg)],
+        "blocks": [block_specs(cfg)
+                   for _ in range(cfg.hybrid.num_mem_blocks)],
+        "sites": [site_specs(cfg) for _ in cfg.hybrid.hybrid_layer_ids],
         "final_norm": L.rms_norm_spec(cfg.d_model),
     }
-    if trailing:
-        sp["trailing"] = stacked(
-            lambda: {
-                "ln": L.rms_norm_spec(cfg.d_model),
-                "mix": ssm.mamba2_specs(cfg),
-            },
-            trailing,
-        )
-    return sp
 
 
-def _mamba_layer(cfg: ModelConfig, lp, x, state=None):
+def _mamba_layer(cfg: ModelConfig, lp, x, t, state=None, length=None):
     with scalpel.function("layer"):
-        h = L.rms_norm(x, lp["ln"])
+        h = L.rms_norm(x + t, lp["ln"], NORM_EPS)
         if state is None:
-            y, st = ssm.mamba2(cfg, lp["mix"], h)
+            y, st = ssm.mamba2(cfg, lp["mix"], h, length=length)
         else:
             y, st = ssm.mamba2_decode(cfg, lp["mix"], h, *state)
         return x + y, st
 
 
-def _apply_shared(cfg: ModelConfig, sp, x, x0, positions):
-    """Shared attention block at 2d on concat(x, x0)."""
-    scfg = _shared_cfg(cfg)
-    with scalpel.function("shared_attn"):
-        cat = jnp.concatenate([x, x0], axis=-1)
-        h = L.rms_norm(cat, sp["ln1"])
-        a = L.attention(scfg, sp["attn"], h, positions)
-        cat = cat + a
-        h = L.rms_norm(cat, sp["ln2"])
-        cat = cat + L.mlp(scfg, sp["mlp"], h)
-        y = jnp.einsum("bse,ed->bsd", cat, sp["down"].astype(x.dtype))
-        y = shard(y, "batch", None, None)
-        scalpel.probe(out=y)
-        return x + y
+def _run(cfg: ModelConfig, lp, x, t, states=None, length=None, remat=None):
+    """One run's stacked layers; ``t`` enters the first layer only."""
+
+    def body(carry, inp):
+        xx, tt = carry
+        if states is None:
+            xx, st = _mamba_layer(cfg, inp, xx, tt, length=length)
+        else:
+            lpi, s_ssm, s_conv = inp
+            xx, st = _mamba_layer(cfg, lpi, xx, tt, (s_ssm, s_conv))
+        return (xx, jnp.zeros_like(tt)), st
+
+    xs = lp if states is None else (lp, *states)
+    (x, _), st = scalpel.scan_with_counters(body, (x, t), xs, remat=remat)
+    return x, st
 
 
-def _apply_shared_decode(cfg: ModelConfig, sp, x, x0, kc, vc, pos):
-    scfg = _shared_cfg(cfg)
-    with scalpel.function("shared_attn"):
-        cat = jnp.concatenate([x, x0], axis=-1)
-        h = L.rms_norm(cat, sp["ln1"])
-        a, kc, vc = L.decode_attention(scfg, sp["attn"], h, kc, vc, pos)
-        cat = cat + a
-        h = L.rms_norm(cat, sp["ln2"])
-        cat = cat + L.mlp(scfg, sp["mlp"], h)
-        y = jnp.einsum("bse,ed->bsd", cat, sp["down"].astype(x.dtype))
-        scalpel.probe(out=y)
-        return x + y, kc, vc
+def _site(cfg: ModelConfig, bp, sp, x, x0, attend):
+    """One site's shared block: ``t`` for its layer's Mamba2, and what
+    ``attend`` (the attention, in its own ``attn`` scope) returns beside
+    its output: the site's KV."""
+    with jax.named_scope("scalpel.shared_attn"), \
+            scalpel.function("shared_attn"):
+        h = L.rms_norm(jnp.concatenate([x, x0], axis=-1), bp["ln_attn"],
+                       NORM_EPS)
+        a, kv = attend(attn_cfg(cfg), bp["attn"], h)
+        h = L.rms_norm(a, bp["ln_mlp"], NORM_EPS)
+        with scalpel.function("mlp"):
+            gu = jnp.einsum("bsd,df->bsf", h, bp["gate_up"].astype(x.dtype))
+            low = jnp.einsum("bsd,dr->bsr", h,
+                             sp["adapter_in"].astype(x.dtype))
+            gu = gu + jnp.einsum("bsr,rf->bsf", low,
+                                 sp["adapter_out"].astype(x.dtype))
+            g, u = jnp.split(gu, 2, axis=-1)
+            m = jax.nn.gelu(g.astype(jnp.float32),
+                            approximate=False).astype(x.dtype) * u
+            m = shard(m, "batch", None, "mlp")
+            m = jnp.einsum("bsf,fd->bsd", m, bp["down"].astype(x.dtype))
+            scalpel.probe(out=m)
+        t = jnp.einsum("bsd,de->bse", m, sp["linear"].astype(x.dtype))
+        t = shard(t, "batch", None, None)
+        scalpel.probe(out=t)
+        return t, kv
+
+
+def _backbone(cfg: ModelConfig, params, x, length=None, remat=None):
+    """Every layer over a whole sequence: (x, [(ssm, conv) states per run],
+    [(k, v) per site])."""
+    x0 = x
+    b, s, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    scale = attn_scale(cfg)
+
+    def attend(acfg, p, h):
+        with scalpel.function("attn"):
+            q, k, v = L._qkv(acfg, p, h, positions)
+            scalpel.probe(q=q, k=k, v=v)
+            o = L.run_attention(acfg, q, k, v, True, scale=scale)
+            y = jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(h.dtype))
+            y = shard(y, "batch", None, None)
+            scalpel.probe(out=y)
+            return y, (k, v)
+
+    t = jnp.zeros_like(x)
+    states, kvs = [], []
+    for (_, _, site), lp in zip(runs(cfg), params["mamba"]):
+        if site is not None:
+            bp = params["blocks"][site % cfg.hybrid.num_mem_blocks]
+            t, kv = _site(cfg, bp, params["sites"][site], x, x0, attend)
+            kvs.append(kv)
+        x, st = _run(cfg, lp, x, t, length=length, remat=remat)
+        states.append(st)
+    return x, states, kvs
 
 
 def forward(cfg: ModelConfig, params, tokens, prefix_embeds=None):
-    every, n_sites, trailing = _geometry(cfg)
     x = L.embed(cfg, params["embed"], tokens)
-    x0 = x
-    positions = jnp.broadcast_to(
-        jnp.arange(x.shape[1], dtype=jnp.int32), x.shape[:2]
-    )
-
-    def group(carry, gp):
-        xx = carry
-
-        def inner(c, lp):
-            out, _ = _mamba_layer(cfg, lp, c)
-            return out, None
-
-        xx, _ = scalpel.scan_with_counters(inner, xx, gp["mamba"])
-        xx = _apply_shared(cfg, params["shared"], xx, x0, positions)
-        return xx, None
-
-    x, _ = scalpel.scan_with_counters(group, x, params["groups"],
-                                      remat=L.remat_policy(cfg))
-    if trailing:
-        def inner(c, lp):
-            out, _ = _mamba_layer(cfg, lp, c)
-            return out, None
-
-        x, _ = scalpel.scan_with_counters(inner, x, params["trailing"])
-    x = L.rms_norm(x, params["final_norm"])
+    x, _, _ = _backbone(cfg, params, x, remat=L.remat_policy(cfg))
+    x = L.rms_norm(x, params["final_norm"], NORM_EPS)
     return L.unembed(cfg, params["embed"], x)
 
 
@@ -163,26 +223,21 @@ def loss_fn(cfg: ModelConfig, params, batch):
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                abstract: bool = False):
-    every, n_sites, trailing = _geometry(cfg)
-    scfg = _shared_cfg(cfg)
+    acfg = attn_cfg(cfg)
     kvd = jnp.dtype(cfg.compute_dtype)
     m = ssm.mamba2_state_specs(cfg, batch)
 
     def stack_n(sd, n):
         return jax.ShapeDtypeStruct((n,) + sd.shape, sd.dtype)
 
+    kv = jax.ShapeDtypeStruct(
+        (batch, cache_len, acfg.n_kv_heads, acfg.resolved_head_dim), kvd)
+    sites = cfg.hybrid.hybrid_layer_ids
     cache = {
-        "mamba_ssm": stack_n(m["ssm"], n_sites * every + trailing),
-        "mamba_conv": stack_n(m["conv"], n_sites * every + trailing),
-        "shared_k": jax.ShapeDtypeStruct(
-            (n_sites, batch, cache_len, scfg.n_kv_heads,
-             scfg.resolved_head_dim), kvd
-        ),
-        "shared_v": jax.ShapeDtypeStruct(
-            (n_sites, batch, cache_len, scfg.n_kv_heads,
-             scfg.resolved_head_dim), kvd
-        ),
-        "x0": jax.ShapeDtypeStruct((batch, 1, cfg.d_model), kvd),
+        "mamba_ssm": [stack_n(m["ssm"], b - a) for a, b, _ in runs(cfg)],
+        "mamba_conv": [stack_n(m["conv"], b - a) for a, b, _ in runs(cfg)],
+        "site_k": [kv for _ in sites],
+        "site_v": [kv for _ in sites],
         "pos": jax.ShapeDtypeStruct((), jnp.int32),
     }
     if abstract:
@@ -194,135 +249,79 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
 
 
 def cache_axes(cfg: ModelConfig):
+    n_runs, n_sites = len(runs(cfg)), len(cfg.hybrid.hybrid_layer_ids)
+    kv = ("batch", "kv_seq", None, None)
     return {
-        "mamba_ssm": ("layers", "batch", "heads", None, None),
-        "mamba_conv": ("layers", "batch", None, None),
-        "shared_k": ("layers", "batch", "kv_seq", None, None),
-        "shared_v": ("layers", "batch", "kv_seq", None, None),
-        "x0": ("batch", None, None),
+        "mamba_ssm": [("layers", "batch", "heads", None, None)] * n_runs,
+        "mamba_conv": [("layers", "batch", None, None)] * n_runs,
+        "site_k": [kv] * n_sites,
+        "site_v": [kv] * n_sites,
         "pos": (),
     }
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens):
-    every, n_sites, trailing = _geometry(cfg)
     x = L.embed(cfg, params["embed"], tokens)
     # zamba's global skip uses the *current token's* embedding in decode
     x0 = x
     pos = cache["pos"]
-    m_ssm, m_conv = cache["mamba_ssm"], cache["mamba_conv"]
+    scale = attn_scale(cfg)
+    t = jnp.zeros_like(x)
+    new = {"mamba_ssm": [], "mamba_conv": [], "site_k": [], "site_v": []}
+    for (_, _, site), lp, s_ssm, s_conv in zip(
+            runs(cfg), params["mamba"], cache["mamba_ssm"],
+            cache["mamba_conv"]):
+        if site is not None:
+            def attend(acfg, p, h, site=site):
+                y, kc, vc = L.decode_attention(
+                    acfg, p, h, cache["site_k"][site], cache["site_v"][site],
+                    pos, scale=scale)
+                return y, (kc, vc)
 
-    def group(carry, inp):
-        xx = carry
-        gp, states_ssm, states_conv, kc, vc = inp
-
-        def inner(c, lp_state):
-            lp, s_ssm, s_conv = lp_state
-            out, (s2, c2) = _mamba_layer(cfg, lp, c, (s_ssm, s_conv))
-            return out, (s2, c2)
-
-        xx, (s2, c2) = scalpel.scan_with_counters(
-            inner, xx, (gp["mamba"], states_ssm, states_conv)
-        )
-        xx, kc, vc = _apply_shared_decode(cfg, params["shared"], xx, x0,
-                                          kc, vc, pos)
-        return xx, (s2, c2, kc, vc)
-
-    gs = n_sites * every
-    x, (s2, c2, k2, v2) = scalpel.scan_with_counters(
-        group, x,
-        (
-            params["groups"],
-            m_ssm[:gs].reshape((n_sites, every) + m_ssm.shape[1:]),
-            m_conv[:gs].reshape((n_sites, every) + m_conv.shape[1:]),
-            cache["shared_k"], cache["shared_v"],
-        ),
-    )
-    new_ssm = s2.reshape((gs,) + m_ssm.shape[1:])
-    new_conv = c2.reshape((gs,) + m_conv.shape[1:])
-    if trailing:
-        def inner(c, lp_state):
-            lp, s_ssm, s_conv = lp_state
-            out, (s2t, c2t) = _mamba_layer(cfg, lp, c, (s_ssm, s_conv))
-            return out, (s2t, c2t)
-
-        x, (st, ct) = scalpel.scan_with_counters(
-            inner, x, (params["trailing"], m_ssm[gs:], m_conv[gs:])
-        )
-        new_ssm = jnp.concatenate([new_ssm, st], axis=0)
-        new_conv = jnp.concatenate([new_conv, ct], axis=0)
-    x = L.rms_norm(x, params["final_norm"])
+            bp = params["blocks"][site % cfg.hybrid.num_mem_blocks]
+            t, (kc, vc) = _site(cfg, bp, params["sites"][site], x, x0,
+                                attend)
+            new["site_k"].append(kc)
+            new["site_v"].append(vc)
+        x, (s2, c2) = _run(cfg, lp, x, t, states=(s_ssm, s_conv))
+        new["mamba_ssm"].append(s2)
+        new["mamba_conv"].append(c2)
+    x = L.rms_norm(x, params["final_norm"], NORM_EPS)
     logits = L.unembed(cfg, params["embed"], x)
-    new_cache = {
-        "mamba_ssm": new_ssm, "mamba_conv": new_conv,
-        "shared_k": k2, "shared_v": v2, "x0": cache["x0"],
-        "pos": pos + 1,
-    }
-    return logits, new_cache
+    return logits, dict(new, pos=pos + 1)
 
 
 def prefill(cfg: ModelConfig, params, tokens, cache_len: int,
-            prefix_embeds=None):
-    """Prompt pass building both mamba states and shared-attn KV caches."""
-    every, n_sites, trailing = _geometry(cfg)
-    scfg = _shared_cfg(cfg)
+            prefix_embeds=None, length=None):
+    """Prompt pass building every Mamba2 state and the sites' KV caches.
+
+    ``length`` (traced i32, None => full width): tokens beyond it are
+    right-pad.  Every state leaving the pass is exactly that of the
+    unpadded prompt (``SUPPORTS_PREFILL_LENGTH``), the logits are read at
+    ``length - 1`` and ``pos = length``."""
     x = L.embed(cfg, params["embed"], tokens)
-    x0 = x
-    b, s, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    s = x.shape[1]
+    x, states, kvs = _backbone(cfg, params, x, length=length)
+    x = L.rms_norm(x, params["final_norm"], NORM_EPS)
+    if length is None:
+        xl = x[:, -1:, :]
+        pos = jnp.asarray(s, jnp.int32)
+    else:
+        xl = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+        pos = jnp.asarray(length, jnp.int32)
+    logits = L.unembed(cfg, params["embed"], xl)
     kvd = jnp.dtype(cfg.compute_dtype)
 
-    def group(carry, gp):
-        xx = carry
+    def to_cache(a):  # [b, s, kv, hd] -> [b, cache_len, kv, hd]
+        a = jnp.pad(a.astype(kvd),
+                    ((0, 0), (0, cache_len - s), (0, 0), (0, 0)))
+        return shard(a, "batch", "kv_seq", None, None)
 
-        def inner(c, lp):
-            out, st = _mamba_layer(cfg, lp, c)
-            return out, st
-
-        xx, (s_ssm, s_conv) = scalpel.scan_with_counters(inner, xx,
-                                                         gp["mamba"])
-        # shared block with KV capture
-        with scalpel.function("shared_attn"):
-            cat = jnp.concatenate([xx, x0], axis=-1)
-            h = L.rms_norm(cat, params["shared"]["ln1"])
-            q, k, v = L._qkv(scfg, params["shared"]["attn"], h, positions)
-            if s <= 256 or cfg.attn_impl == "reference":
-                a = L.reference_attention(scfg, q, k, v, True)
-            else:
-                a = L.flash_attention_xla(scfg, q, k, v, True)
-            y = jnp.einsum("bshk,hkd->bsd", a,
-                           params["shared"]["attn"]["wo"].astype(xx.dtype))
-            cat = cat + y
-            h = L.rms_norm(cat, params["shared"]["ln2"])
-            cat = cat + L.mlp(scfg, params["shared"]["mlp"], h)
-            y = jnp.einsum("bse,ed->bsd", cat,
-                           params["shared"]["down"].astype(xx.dtype))
-            xx = xx + y
-        pad = cache_len - s
-        kc = jnp.pad(k.astype(kvd), ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vc = jnp.pad(v.astype(kvd), ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return xx, (s_ssm, s_conv, kc, vc)
-
-    x, (s_ssm, s_conv, kcs, vcs) = scalpel.scan_with_counters(
-        group, x, params["groups"]
-    )
-    new_ssm = s_ssm.reshape((n_sites * every,) + s_ssm.shape[2:])
-    new_conv = s_conv.reshape((n_sites * every,) + s_conv.shape[2:])
-    if trailing:
-        def inner(c, lp):
-            out, st = _mamba_layer(cfg, lp, c)
-            return out, st
-
-        x, (st, ct) = scalpel.scan_with_counters(inner, x,
-                                                 params["trailing"])
-        new_ssm = jnp.concatenate([new_ssm, st], axis=0)
-        new_conv = jnp.concatenate([new_conv, ct], axis=0)
-    x = L.rms_norm(x, params["final_norm"])
-    logits = L.unembed(cfg, params["embed"], x[:, -1:, :])
     cache = {
-        "mamba_ssm": new_ssm, "mamba_conv": new_conv,
-        "shared_k": kcs, "shared_v": vcs,
-        "x0": x0[:, -1:, :].astype(kvd),
-        "pos": jnp.asarray(s, jnp.int32),
+        "mamba_ssm": [st[0] for st in states],
+        "mamba_conv": [st[1] for st in states],
+        "site_k": [to_cache(k) for k, _ in kvs],
+        "site_v": [to_cache(v) for _, v in kvs],
+        "pos": pos,
     }
     return cache, logits

@@ -142,11 +142,13 @@ def _repeat_kv(k, n_rep: int):
 
 
 def reference_attention(cfg: ModelConfig, q, k, v, causal: bool = True,
-                        window: int = 0):
-    """Materialized-probs attention (smoke-scale only).  Probes entropy."""
+                        window: int = 0, scale: float | None = None):
+    """Materialized-probs attention (smoke-scale only).  Probes entropy.
+    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``)."""
     k = _repeat_kv(k, q.shape[2] // k.shape[2])
     v = _repeat_kv(v, q.shape[2] // v.shape[2])
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     sq, sk = q.shape[1], k.shape[1]
     qpos = jnp.arange(sq)[:, None] + (sk - sq)
@@ -164,7 +166,8 @@ def reference_attention(cfg: ModelConfig, q, k, v, causal: bool = True,
 
 
 def flash_attention_xla(cfg: ModelConfig, q, k, v, causal: bool = True,
-                        window: int = 0, triangle: bool | None = None):
+                        window: int = 0, triangle: bool | None = None,
+                        scale: float | None = None):
     """Chunked online-softmax attention, pure JAX (lowerable everywhere).
 
     ``triangle=True`` (default for causal self-attention): one scan over the
@@ -185,7 +188,8 @@ def flash_attention_xla(cfg: ModelConfig, q, k, v, causal: bool = True,
     nq = (sq + bq - 1) // bq
     nk = (sk + bkv - 1) // bkv
     assert sq % bq == 0 and sk % bkv == 0, (sq, bq, sk, bkv)
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     offs = sk - sq  # query i attends keys <= i + offs
 
     qb = q.reshape(b, nq, bq, h, d)
@@ -284,8 +288,9 @@ def flash_attention_xla(cfg: ModelConfig, q, k, v, causal: bool = True,
 
 
 def run_attention(cfg: ModelConfig, q, k, v, causal: bool = True,
-                  window: int = 0):
+                  window: int = 0, scale: float | None = None):
     """Dispatch the sequence-mixing implementation, with head padding.
+    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
 
     When n_heads does not divide the TP axis (qwen3-14b: 40 heads on a
     16-way model axis) the heads are PADDED to the next multiple so the
@@ -298,7 +303,7 @@ def run_attention(cfg: ModelConfig, q, k, v, causal: bool = True,
     impl = cfg.attn_impl
     sq = q.shape[1]
     if impl == "reference" or sq <= 256:
-        return reference_attention(cfg, q, k, v, causal, window)
+        return reference_attention(cfg, q, k, v, causal, window, scale)
 
     tp = axis_size("model")
     h = q.shape[2]
@@ -323,15 +328,16 @@ def run_attention(cfg: ModelConfig, q, k, v, causal: bool = True,
         out = kops.flash_attention(
             q, k, v, causal=causal,
             block_q=cfg.flash_block_q, block_kv=cfg.flash_block_kv,
+            scale=scale,
         )
     elif impl == "flash_xla_naive":
         out = flash_attention_xla(cfg, q, k, v, causal, window,
-                                  triangle=False)
+                                  triangle=False, scale=scale)
     elif impl == "flash_xla_tri":
         out = flash_attention_xla(cfg, q, k, v, causal, window,
-                                  triangle=True)
+                                  triangle=True, scale=scale)
     else:  # "flash_xla" and default: custom-VJP memory-optimal path
-        out = flash_attention_cvjp(cfg, q, k, v, causal, window)
+        out = flash_attention_cvjp(cfg, q, k, v, causal, window, scale)
     if sliced:
         out = out[:, :, :h]
     return out
@@ -352,13 +358,15 @@ def attention(cfg: ModelConfig, p, x, positions, causal: bool = True,
         return y
 
 
-def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
+def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos,
+                     scale: float | None = None):
     """One-token decode against a sequence-sharded KV cache.
 
     x: [b, 1, d]; cache_{k,v}: [b, S, kv, hd] with S sharded over 'model'
     (flash-decoding-style sequence parallelism — GSPMD inserts the small
     softmax all-reduces); ``pos`` scalar int32 — write position of the new
     token (uniform across the batch, standard static-batch serving).
+    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
     Returns (y [b,1,d], cache_k', cache_v').
 
     Grouped-query form: the query heads are grouped by the KV head they
@@ -380,7 +388,8 @@ def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
         _, _, h, hd = q.shape
         S, kv = cache_k.shape[1], cache_k.shape[2]
         qg = q.reshape(b, 1, kv, h // kv, hd)  # head g*(h/kv)+r -> (g, r)
-        scale = 1.0 / math.sqrt(hd)
+        if scale is None:
+            scale = 1.0 / math.sqrt(hd)
         s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, cache_k.astype(x.dtype),
                        preferred_element_type=jnp.float32) * scale
         kpos = jnp.arange(S)
@@ -602,11 +611,12 @@ _flash_cvjp.defvjp(_flash_cvjp_fwd, _flash_cvjp_bwd)
 
 
 def flash_attention_cvjp(cfg: ModelConfig, q, k, v, causal: bool = True,
-                         window: int = 0):
+                         window: int = 0, scale: float | None = None):
     """Flash attention with the memory-optimal custom-VJP backward.
 
     GQA is handled by repeating KV up front (the repeat is elementwise and
-    fuses; the backward sums gradient over the repeat groups).
+    fuses; the backward sums gradient over the repeat groups).  ``scale``
+    multiplies the scores (default ``1/sqrt(head_dim)``).
     """
     b, sq, h, d = q.shape
     kvh = k.shape[2]
@@ -619,7 +629,7 @@ def flash_attention_cvjp(cfg: ModelConfig, q, k, v, causal: bool = True,
     bkv = min(cfg.flash_block_kv, sk)
     assert sq % bq == 0 and sk % bkv == 0, (sq, bq, sk, bkv)
     out = _flash_cvjp(q, k, v, causal, window, bq, bkv,
-                      1.0 / math.sqrt(d))
+                      1.0 / math.sqrt(d) if scale is None else scale)
     return out
 
 

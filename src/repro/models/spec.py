@@ -4,7 +4,7 @@ Families:
   dense   — decoder-only transformer (GQA, optional qk_norm, no-bias)
   moe     — dense backbone with MoE FFN (top-k, optional dense residual)
   ssm     — xLSTM (alternating mLSTM / sLSTM blocks)
-  hybrid  — Zamba2 (Mamba2 backbone + shared attention block)
+  hybrid  — Zamba2 (Mamba2 backbone + alternating shared attention blocks)
   encdec  — encoder-decoder (seamless: audio frontend stub + text decoder)
   vlm     — pixtral (ViT frontend stub + dense decoder backbone)
 """
@@ -30,12 +30,27 @@ class SSMConfig:
     head_dim: int = 64       # mamba2 head dim
     chunk: int = 256         # chunked-scan block length
     slstm_every: int = 2     # xlstm: every k-th block is sLSTM (rest mLSTM)
+    n_groups: int = 1        # mamba2: groups sharing B, C and the gated norm
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
-    attn_every: int = 6      # shared attention block applied every k layers
-    concat_embedding: bool = True  # zamba: shared block sees [x, embed] concat
+    """Zamba2's keys: the layers in ``hybrid_layer_ids`` first run a shared
+    attention+MLP block (block ``k % num_mem_blocks`` at the k-th of them),
+    each such site with its own rank-``adapter_rank`` MLP adapter."""
+
+    hybrid_layer_ids: tuple[int, ...] = ()
+    num_mem_blocks: int = 2
+    adapter_rank: int = 128
+    # read by nothing: configuration files of the other families written
+    # before the published keys above still name them
+    attn_every: int = 6
+    concat_embedding: bool = True
+
+    def __post_init__(self):
+        # a JSON list arrives here; the config must stay hashable
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(int(i) for i in self.hybrid_layer_ids))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -53,17 +53,32 @@ def causal_conv1d(x, w, state=None, length=None):
 # Mamba2 (SSD)
 # ---------------------------------------------------------------------------
 
-def mamba2_specs(cfg: ModelConfig) -> dict:
-    d = cfg.d_model
-    di = cfg.ssm.expand * d
+# the gated RMSNorm's epsilon: Zamba2's rms_norm_eps (Zamba2 is the one
+# family that runs Mamba2)
+NORM_EPS = 1e-5
+
+
+def _mamba2_dims(cfg: ModelConfig):
+    """(d_inner, heads, head_dim, groups, d_state)."""
+    di = cfg.ssm.expand * cfg.d_model
     hd = cfg.ssm.head_dim
     nh = di // hd
-    N = cfg.ssm.d_state
+    g = cfg.ssm.n_groups
+    if nh % g:
+        raise ValueError(f"{nh} mamba2 heads do not split over {g} groups")
+    return di, nh, hd, g, cfg.ssm.d_state
+
+
+def mamba2_specs(cfg: ModelConfig) -> dict:
+    d = cfg.d_model
+    di, nh, _, g, N = _mamba2_dims(cfg)
     kw = cfg.ssm.d_conv
-    conv_ch = di + 2 * N  # x + B + C go through the conv
+    conv_ch = di + 2 * g * N  # x + B + C go through the conv
     return {
-        "in_proj": P((d, 2 * di + 2 * N + nh),
-                     ("embed", "heads")),  # z | x | B | C | dt
+        # z | x | B | C | dt, stored [out, in] (as the published checkpoint
+        # holds it): the layout the TPU's decode matmul reads, so no decode
+        # megastep lays the weights out anew
+        "in_proj": P((2 * di + 2 * g * N + nh, d), ("heads", "embed")),
         "conv_w": P((kw, conv_ch), ("conv", None), scale=0.5),
         "conv_b": P((conv_ch,), (None,), init="zeros"),
         "A_log": P((nh,), (None,), init="zeros", scale=1.0),
@@ -76,111 +91,122 @@ def mamba2_specs(cfg: ModelConfig) -> dict:
 
 def _ssd_chunked(xh, dt, da_log, B, C, S0=None, chunk=256):
     """SSD scan. xh:[b,s,h,p] dt:[b,s,h] da_log:[b,s,h] (log decay per step)
-    B,C: [b,s,N].  Returns (y [b,s,h,p], S_final [b,h,p,N])."""
+    B,C: [b,s,g,N], the h heads split evenly over the g groups in order
+    (head i reads group i // (h/g)).  Returns (y [b,s,h,p], S_final
+    [b,h,p,N])."""
     b, s, h, p = xh.shape
-    N = B.shape[-1]
+    g, N = B.shape[2], B.shape[3]
+    r = h // g
     Q = min(chunk, s)
     if s % Q:
         # pad to a chunk multiple with identity steps (dt=0, da_log=0 keeps
         # the state; padded y rows are sliced off below)
         pad = Q - s % Q
-        xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        da_log = jnp.pad(da_log, ((0, 0), (0, pad), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-        y, Sf = _ssd_chunked(xh, dt, da_log, B, C, S0=S0, chunk=Q)
+        pad4 = ((0, 0), (0, pad), (0, 0), (0, 0))
+        y, Sf = _ssd_chunked(
+            jnp.pad(xh, pad4), jnp.pad(dt, pad4[:3]), jnp.pad(da_log, pad4[:3]),
+            jnp.pad(B, pad4), jnp.pad(C, pad4), S0=S0, chunk=Q)
         return y[:, :s], Sf
     nc = s // Q
-    xc = xh.reshape(b, nc, Q, h, p)
-    dtc = dt.reshape(b, nc, Q, h)
-    alc = da_log.reshape(b, nc, Q, h)
-    Bc = B.reshape(b, nc, Q, N)
-    Cc = C.reshape(b, nc, Q, N)
+    xc = xh.reshape(b, nc, Q, g, r, p)
+    dtc = dt.reshape(b, nc, Q, g, r)
+    alc = da_log.reshape(b, nc, Q, g, r)
+    Bc = B.reshape(b, nc, Q, g, N)
+    Cc = C.reshape(b, nc, Q, g, N)
 
     def chunk_step(S, inp):
-        xq, dtq, alq, Bq, Cq = inp  # [b,Q,...]
-        cum = jnp.cumsum(alq, axis=1)  # [b,Q,h] log decay from chunk start
-        total = cum[:, -1]  # [b,h]
+        xq, dtq, alq, Bq, Cq = inp  # [b,Q,...]; S [b,g,r,p,N]
+        cum = jnp.cumsum(alq, axis=1)  # [b,Q,g,r] log decay from chunk start
+        total = cum[:, -1]  # [b,g,r]
         # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j<=i
-        li = cum[:, :, None, :] - cum[:, None, :, :]  # [b,Q,Q,h]
+        li = cum[:, :, None] - cum[:, None, :]  # [b,Q,Q,g,r]
         mask = jnp.tril(jnp.ones((Q, Q), bool))
-        L = jnp.where(mask[None, :, :, None], jnp.exp(li), 0.0)
-        G = jnp.einsum("bin,bjn->bij", Cq, Bq)  # [b,Q,Q]
-        M = G[..., None] * L * dtq[:, None, :, :]  # [b,i,j,h]
-        y_intra = jnp.einsum("bijh,bjhp->bihp", M.astype(xq.dtype), xq)
+        L = jnp.where(mask[None, :, :, None, None], jnp.exp(li), 0.0)
+        G = jnp.einsum("bign,bjgn->bijg", Cq, Bq)  # [b,Q,Q,g]
+        M = G[..., None] * L * dtq[:, None]  # [b,i,j,g,r]
+        y_intra = jnp.einsum("bijgr,bjgrp->bigrp", M.astype(xq.dtype), xq)
         # inter-chunk: contribution of incoming state
-        decay_in = jnp.exp(cum)  # [b,Q,h]
+        decay_in = jnp.exp(cum)  # [b,Q,g,r]
         y_inter = jnp.einsum(
-            "bin,bhpn,bih->bihp", Cq.astype(jnp.float32),
-            S.astype(jnp.float32), decay_in,
+            "bign,bgrpn,bigr->bigrp", Cq.astype(jnp.float32), S, decay_in,
         ).astype(xq.dtype)
         # state update: S' = S*exp(total) + sum_j exp(total-cum_j) dt_j B_j x_j
-        w = jnp.exp(total[:, None, :] - cum) * dtq  # [b,Q,h]
+        w = jnp.exp(total[:, None] - cum) * dtq  # [b,Q,g,r]
         dS = jnp.einsum(
-            "bjn,bjhp,bjh->bhpn", Bq.astype(jnp.float32),
+            "bjgn,bjgrp,bjgr->bgrpn", Bq.astype(jnp.float32),
             xq.astype(jnp.float32), w,
         )
-        S2 = S * jnp.exp(total)[:, :, None, None] + dS
+        S2 = S * jnp.exp(total)[..., None, None] + dS
         return S2, y_intra + y_inter
 
     S0 = (jnp.zeros((b, h, p, N), jnp.float32) if S0 is None else S0)
-    inputs = (
-        xc.transpose(1, 0, 2, 3, 4),
-        dtc.transpose(1, 0, 2, 3),
-        alc.transpose(1, 0, 2, 3),
-        Bc.transpose(1, 0, 2, 3),
-        Cc.transpose(1, 0, 2, 3),
+    inputs = tuple(jnp.moveaxis(a, 1, 0) for a in (xc, dtc, alc, Bc, Cc))
+    Sf, ys = jax.lax.scan(chunk_step, S0.reshape(b, g, r, p, N), inputs)
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, s, h, p)
+    return y, Sf.reshape(b, h, p, N)
+
+
+def _mamba2_in(cfg: ModelConfig, p, x, conv_state, length=None):
+    """in_proj, causal conv and SiLU: (z, x [.., g, r, p], B, C [.., g, N],
+    dt [.., g, r], A [g, r], conv_state)."""
+    di, nh, hd, g, N = _mamba2_dims(cfg)
+    zxbcdt = jnp.einsum("bsd,ed->bse", x, p["in_proj"].astype(x.dtype))
+    z, xbc, dtp = jnp.split(zxbcdt, [di, 2 * di + 2 * g * N], axis=-1)
+    xbc, conv_state = causal_conv1d(
+        xbc, p["conv_w"].astype(x.dtype), conv_state, length=length
     )
-    Sf, ys = jax.lax.scan(chunk_step, S0, inputs)
-    y = ys.transpose(1, 0, 2, 3, 4).reshape(b, s, h, p)
-    return y, Sf
+    xbc = jax.nn.silu(
+        (xbc + p["conv_b"].astype(x.dtype)).astype(jnp.float32)
+    ).astype(x.dtype)
+    xi, B, C = jnp.split(xbc, [di, di + g * N], axis=-1)
+    lead = x.shape[:2]
+    dt = jax.nn.softplus(
+        dtp.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)
+    )  # [b,s,nh]
+    A = -jnp.exp(p["A_log"].astype(jnp.float32))  # [nh] negative
+    return (z, xi.reshape(lead + (g, nh // g, hd)), B.reshape(lead + (g, N)),
+            C.reshape(lead + (g, N)), dt.reshape(lead + (g, nh // g)),
+            A.reshape(g, nh // g), conv_state)
 
 
-def _mamba2_project(cfg: ModelConfig, p, x):
-    d = cfg.d_model
-    di = cfg.ssm.expand * d
-    N = cfg.ssm.d_state
-    hd = cfg.ssm.head_dim
-    nh = di // hd
-    zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"].astype(x.dtype))
-    z, xi, B, C, dtp = jnp.split(
-        zxbcdt, [di, 2 * di, 2 * di + N, 2 * di + 2 * N], axis=-1
-    )
-    return z, xi, B, C, dtp, di, N, hd, nh
+def _mamba2_out(cfg: ModelConfig, p, y, xg, z):
+    """D skip, gated RMSNorm over each group's channels, out_proj.
+    y, xg: [b,s,g,r,p]; z: [b,s,d_inner]."""
+    b, s, g, r, hd = y.shape
+    D = p["D"].astype(y.dtype).reshape(g, r, 1)
+    y = (y + xg * D).reshape(b, s, g, r * hd)
+    gate = jax.nn.silu(z.astype(jnp.float32)).reshape(b, s, g, r * hd)
+    yf = y.astype(jnp.float32) * gate
+    yf = yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + NORM_EPS)
+    y = (yf.reshape(b, s, g * r * hd)
+         * p["norm"].astype(jnp.float32)).astype(z.dtype)
+    return jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(z.dtype))
 
 
-def mamba2(cfg: ModelConfig, p, x, state=None, conv_state=None):
-    """Full-sequence Mamba2 mixer. x: [b,s,d] -> (y, (S, conv_state))."""
+def mamba2(cfg: ModelConfig, p, x, state=None, conv_state=None,
+           length=None):
+    """Full-sequence Mamba2 mixer. x: [b,s,d] -> (y, (S, conv_state)).
+
+    ``length`` (traced i32, None => s): positions >= length are right-pad.
+    They take dt = 0, so the SSD state passes through them unchanged, and
+    the conv state is the window ending at ``length``: the carried state is
+    exactly that of an unpadded run (pad rows emit outputs nothing reads).
+    """
     with scalpel.function("ssm"):
         b, s, d = x.shape
-        z, xi, B, C, dtp, di, N, hd, nh = _mamba2_project(cfg, p, x)
-        xbc = jnp.concatenate([xi, B, C], axis=-1)
-        xbc, conv_state = causal_conv1d(
-            xbc, p["conv_w"].astype(x.dtype), conv_state
-        )
-        xbc = jax.nn.silu(
-            (xbc + p["conv_b"].astype(x.dtype)).astype(jnp.float32)
-        ).astype(x.dtype)
-        xi, B, C = jnp.split(xbc, [di, di + N], axis=-1)
-        dt = jax.nn.softplus(
-            dtp.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)
-        )  # [b,s,nh]
-        A = -jnp.exp(p["A_log"].astype(jnp.float32))  # [nh] negative
-        da_log = dt * A[None, None, :]
-        xh = xi.reshape(b, s, nh, hd)
-        xh = shard(xh, "batch", None, "heads", None)
-        y, S = _ssd_chunked(xh, dt, da_log, B, C, S0=state,
-                            chunk=cfg.ssm.chunk)
+        z, xg, B, C, dt, A, conv_state = _mamba2_in(cfg, p, x, conv_state,
+                                                    length)
+        if length is not None:
+            valid = (jnp.arange(s) < length)[None, :, None, None]
+            dt = jnp.where(valid, dt, 0.0)
+        g, r, hd = xg.shape[2:]
+        xh = shard(xg.reshape(b, s, g * r, hd), "batch", None, "heads", None)
+        with jax.named_scope("scalpel.ssm.scan"):
+            y, S = _ssd_chunked(xh, dt.reshape(b, s, g * r),
+                                (dt * A).reshape(b, s, g * r), B, C,
+                                S0=state, chunk=cfg.ssm.chunk)
         scalpel.probe(state=S)
-        y = y + xh * p["D"].astype(x.dtype)[None, None, :, None]
-        y = y.reshape(b, s, di)
-        # gated RMSNorm (mamba2 style)
-        from .layers import rms_norm
-
-        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),
-                     p["norm"])
-        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
+        out = _mamba2_out(cfg, p, y.reshape(xg.shape), xg, z)
         out = shard(out, "batch", None, None)
         scalpel.probe(out=out)
         return out, (S, conv_state)
@@ -189,53 +215,32 @@ def mamba2(cfg: ModelConfig, p, x, state=None, conv_state=None):
 def mamba2_decode(cfg: ModelConfig, p, x, state, conv_state):
     """One-token decode. x: [b,1,d]; state [b,h,p,N]; conv [b,k-1,ch]."""
     with scalpel.function("ssm"):
-        b = x.shape[0]
-        z, xi, B, C, dtp, di, N, hd, nh = _mamba2_project(cfg, p, x)
-        xbc = jnp.concatenate([xi, B, C], axis=-1)
-        xbc, conv_state = causal_conv1d(
-            xbc, p["conv_w"].astype(x.dtype), conv_state
-        )
-        xbc = jax.nn.silu(
-            (xbc + p["conv_b"].astype(x.dtype)).astype(jnp.float32)
-        ).astype(x.dtype)
-        xi, B, C = jnp.split(xbc, [di, di + N], axis=-1)
-        dt = jax.nn.softplus(
-            dtp.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)
-        )[:, 0]  # [b,nh]
-        A = -jnp.exp(p["A_log"].astype(jnp.float32))
-        da = jnp.exp(dt * A[None, :])  # [b,nh]
-        xh = xi.reshape(b, nh, hd)
-        Bq = B[:, 0]  # [b,N]
-        Cq = C[:, 0]
-        state = state * da[:, :, None, None] + jnp.einsum(
-            "bn,bhp,bh->bhpn", Bq.astype(jnp.float32),
-            xh.astype(jnp.float32), dt,
-        )
+        z, xg, B, C, dt, A, conv_state = _mamba2_in(cfg, p, x, conv_state)
+        b, _, g, r, hd = xg.shape
+        with jax.named_scope("scalpel.ssm.step"):
+            S = state.reshape(b, g, r, hd, -1)
+            dt1 = dt[:, 0]  # [b,g,r]
+            S = S * jnp.exp(dt1 * A)[..., None, None] + jnp.einsum(
+                "bgn,bgrp,bgr->bgrpn", B[:, 0].astype(jnp.float32),
+                xg[:, 0].astype(jnp.float32), dt1,
+            )
+            y = jnp.einsum(
+                "bgn,bgrpn->bgrp", C[:, 0].astype(jnp.float32), S
+            ).astype(x.dtype)
+            state = S.reshape(state.shape)
         scalpel.probe(state=state)
-        y = jnp.einsum(
-            "bn,bhpn->bhp", Cq.astype(jnp.float32), state
-        ).astype(x.dtype)
-        y = y + xh * p["D"].astype(x.dtype)[None, :, None]
-        y = y.reshape(b, 1, di)
-        from .layers import rms_norm
-
-        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),
-                     p["norm"])
-        out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(x.dtype))
+        out = _mamba2_out(cfg, p, y[:, None], xg, z)
         scalpel.probe(out=out)
         return out, (state, conv_state)
 
 
 def mamba2_state_specs(cfg: ModelConfig, batch: int):
-    di = cfg.ssm.expand * cfg.d_model
-    nh = di // cfg.ssm.head_dim
-    conv_ch = di + 2 * cfg.ssm.d_state
+    di, nh, hd, g, N = _mamba2_dims(cfg)
     return {
-        "ssm": jax.ShapeDtypeStruct(
-            (batch, nh, cfg.ssm.head_dim, cfg.ssm.d_state), jnp.float32
-        ),
+        "ssm": jax.ShapeDtypeStruct((batch, nh, hd, N), jnp.float32),
         "conv": jax.ShapeDtypeStruct(
-            (batch, cfg.ssm.d_conv - 1, conv_ch), jnp.dtype(cfg.compute_dtype)
+            (batch, cfg.ssm.d_conv - 1, di + 2 * g * N),
+            jnp.dtype(cfg.compute_dtype)
         ),
     }
 
