@@ -358,25 +358,63 @@ def attention(cfg: ModelConfig, p, x, positions, causal: bool = True,
         return y
 
 
-def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos,
-                     scale: float | None = None):
-    """One-token decode against a sequence-sharded KV cache.
+def decode_qkv(cfg: ModelConfig, p, x, pos):
+    """The decode token's q, k and v. x: [b, 1, d]; ``pos`` its position."""
+    positions = jnp.broadcast_to(pos, (x.shape[0], 1)).astype(jnp.int32)
+    return _qkv(cfg, p, x, positions)
 
-    x: [b, 1, d]; cache_{k,v}: [b, S, kv, hd] with S sharded over 'model'
-    (flash-decoding-style sequence parallelism — GSPMD inserts the small
-    softmax all-reduces); ``pos`` scalar int32 — write position of the new
-    token (uniform across the batch, standard static-batch serving).
-    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
-    Returns (y [b,1,d], cache_k', cache_v').
+
+def decode_attend(cfg: ModelConfig, p, x, q, cache_k, cache_v, pos,
+                  scale: float | None = None, layout: str = "bkgd"):
+    """Score and mix one query token against a cache that already holds it.
+
+    q: [b, 1, h, hd]; cache_{k,v} laid out as ``layout`` names it: "bkgd"
+    is [b, S, kv, hd], "bgkd" is [b, kv, S, hd]; slots past ``pos`` are
+    masked.  ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
+    Returns y [b, 1, d] after the output projection.
 
     Grouped-query form: the query heads are grouped by the KV head they
     share and contracted against the cache as stored, so the cache is never
     repeated to the query heads (decode is bound by its KV reads).
     """
+    b, _, h, hd = q.shape
+    S, kv = cache_k.shape[layout.index("k")], cache_k.shape[layout.index("g")]
+    qg = q.reshape(b, 1, kv, h // kv, hd)  # head g*(h/kv)+r -> (g, r)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    s = jnp.einsum(f"bqgrd,{layout}->bgrqk", qg, cache_k.astype(x.dtype),
+                   preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(S)
+    valid = kpos <= pos
+    if cfg.sliding_window:
+        valid = valid & (kpos > pos - cfg.sliding_window)
+    s = jnp.where(valid, s, -1e30)
+    p_attn = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum(f"bgrqk,{layout}->bqgrd", p_attn.astype(x.dtype),
+                     cache_v.astype(x.dtype)).reshape(b, 1, h, hd)
+    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    if cfg.use_bias:
+        y = y + p["bo"].astype(x.dtype)
+    scalpel.probe(out=y)
+    return y
+
+
+def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos,
+                     scale: float | None = None):
+    """One-token decode against one layer's sequence-sharded KV cache.
+
+    x: [b, 1, d]; cache_{k,v}: [b, S, kv, hd] with S sharded over 'model'
+    (flash-decoding-style sequence parallelism — GSPMD inserts the small
+    softmax all-reduces); ``pos``: the new token's write position, int32,
+    scalar for the batch or one per lane under ``vmap``.
+    Returns (y [b,1,d], cache_k', cache_v').
+
+    For a caller that holds one cache per attention site.  The dense layer
+    stack writes and reads its own stacked cache (``transformer.
+    decode_step``) through ``decode_qkv`` and ``decode_attend``.
+    """
     with scalpel.function("attn"):
-        b = x.shape[0]
-        positions = jnp.broadcast_to(pos, (b, 1)).astype(jnp.int32)
-        q, k_new, v_new = _qkv(cfg, p, x, positions)
+        q, k_new, v_new = decode_qkv(cfg, p, x, pos)
         cache_k = jax.lax.dynamic_update_slice_in_dim(
             cache_k, k_new.astype(cache_k.dtype), pos, axis=1
         )
@@ -385,25 +423,7 @@ def decode_attention(cfg: ModelConfig, p, x, cache_k, cache_v, pos,
         )
         cache_k = shard(cache_k, "batch", "kv_seq", None, None)
         cache_v = shard(cache_v, "batch", "kv_seq", None, None)
-        _, _, h, hd = q.shape
-        S, kv = cache_k.shape[1], cache_k.shape[2]
-        qg = q.reshape(b, 1, kv, h // kv, hd)  # head g*(h/kv)+r -> (g, r)
-        if scale is None:
-            scale = 1.0 / math.sqrt(hd)
-        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, cache_k.astype(x.dtype),
-                       preferred_element_type=jnp.float32) * scale
-        kpos = jnp.arange(S)
-        valid = kpos <= pos
-        if cfg.sliding_window:
-            valid = valid & (kpos > pos - cfg.sliding_window)
-        s = jnp.where(valid, s, -1e30)
-        p_attn = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bgrqk,bkgd->bqgrd", p_attn.astype(x.dtype),
-                         cache_v.astype(x.dtype)).reshape(b, 1, h, hd)
-        y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-        if cfg.use_bias:
-            y = y + p["bo"].astype(x.dtype)
-        scalpel.probe(out=y)
+        y = decode_attend(cfg, p, x, q, cache_k, cache_v, pos, scale)
         return y, cache_k, cache_v
 
 

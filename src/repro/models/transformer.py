@@ -103,7 +103,7 @@ def prefill(cfg: ModelConfig, params, tokens, cache_len: int,
             prefix_embeds=None, length=None):
     """Run the full prompt, build a KV cache of size ``cache_len``.
 
-    Returns (cache, last_logits).  cache: {"k","v": [nL,b,S,kv,hd], "pos"}.
+    Returns (cache, last_logits).  cache: {"k","v": [nL,b,kv,S,hd], "pos"}.
 
     ``length`` (traced i32, None => full width): tokens beyond it are
     right-pad.  Causal attention keeps valid positions exact (they never
@@ -132,12 +132,8 @@ def prefill(cfg: ModelConfig, params, tokens, cache_len: int,
             xx = xx + y
             h = L.rms_norm(xx, lp["ln2"])
             xx = xx + _ffn(cfg, lp, h)
-        pad = cache_len - s
-        kc = jnp.pad(k.astype(kvd), ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vc = jnp.pad(v.astype(kvd), ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kc = shard(kc, "batch", "kv_seq", None, None)
-        vc = shard(vc, "batch", "kv_seq", None, None)
-        return xx, {"k": kc, "v": vc}
+        return xx, {"k": _to_cache(k.astype(kvd), cache_len),
+                    "v": _to_cache(v.astype(kvd), cache_len)}
 
     x, kvs = scalpel.scan_with_counters(body, x, params["layers"])
     x = L.rms_norm(x, params["final_norm"])
@@ -152,10 +148,22 @@ def prefill(cfg: ModelConfig, params, tokens, cache_len: int,
     return cache, logits
 
 
+def _to_cache(a, cache_len: int):
+    """One layer's prompt K or V [b, s, kv, hd] -> its cache [b, kv, S, hd]."""
+    a = jnp.swapaxes(a, 1, 2)
+    a = jnp.pad(a, ((0, 0), (0, 0), (0, cache_len - a.shape[2]), (0, 0)))
+    return shard(a, "batch", None, "kv_seq", None)
+
+
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                abstract: bool = False):
+    """The stacked cache, head-major: ``[layers, b, kv, S, hd]``.
+
+    Each KV head's positions lie contiguous, in the order the grouped
+    score and mix contract them, so the decode loop reads layer ``i``
+    where it lies (a sequence-major cache costs a per-layer copy)."""
     kvd = jnp.dtype(cfg.compute_dtype)
-    shape = (cfg.n_layers, batch, cache_len, cfg.n_kv_heads,
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, cache_len,
              cfg.resolved_head_dim)
     if abstract:
         arr = jax.ShapeDtypeStruct(shape, kvd)
@@ -167,30 +175,46 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
 
 
 def cache_axes(cfg: ModelConfig):
-    kv = ("layers", "batch", "kv_seq", None, None)
+    kv = ("layers", "batch", None, "kv_seq", None)
     return {"k": kv, "v": kv, "pos": ()}
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens):
-    """One decode step for the whole batch. tokens: [b,1] int32."""
+    """One decode step for the whole batch. tokens: [b,1] int32.
+
+    The stacked cache rides the layer loop's carry, not its xs/ys: layer
+    ``i`` writes the new token's K and V as one row at ``(i, :, :, pos)``
+    and its score and mix read layer ``i`` where it lies, so a step never
+    copies, slices out or restacks the cache (under the serve driver's
+    lane ``vmap`` the row write is a scatter of one row a lane, in place).
+    """
     x = L.embed(cfg, params["embed"], tokens)
     pos = cache["pos"]
 
-    def body(carry, layer_in):
-        lp, kc, vc = layer_in
-        xx = carry
+    def write(stack, new, i):  # new: [b, 1, kv, hd] -> row (i, :, :, pos)
+        row = jnp.swapaxes(new, 1, 2)[None].astype(stack.dtype)
+        stack = jax.lax.dynamic_update_slice(stack, row, (i, 0, 0, pos, 0))
+        return shard(stack, "layers", "batch", None, "kv_seq", None)
+
+    def body(carry, lp):
+        xx, ks, vs, i = carry
         with scalpel.function("layer"):
             h = L.rms_norm(xx, lp["ln1"])
-            y, kc, vc = L.decode_attention(cfg, lp["attn"], h, kc, vc, pos)
+            with scalpel.function("attn"):
+                q, k_new, v_new = L.decode_qkv(cfg, lp["attn"], h, pos)
+                ks, vs = write(ks, k_new, i), write(vs, v_new, i)
+                y = L.decode_attend(
+                    cfg, lp["attn"], h, q,
+                    jax.lax.dynamic_index_in_dim(ks, i, keepdims=False),
+                    jax.lax.dynamic_index_in_dim(vs, i, keepdims=False),
+                    pos, layout="bgkd")
             xx = xx + y
             h = L.rms_norm(xx, lp["ln2"])
             xx = xx + _ffn(cfg, lp, h)
-        return xx, {"k": kc, "v": vc}
+        return (xx, ks, vs, i + 1), None
 
-    x, kvs = scalpel.scan_with_counters(
-        body, x, (params["layers"], cache["k"], cache["v"])
-    )
+    (x, ks, vs, _), _ = scalpel.scan_with_counters(
+        body, (x, cache["k"], cache["v"], jnp.int32(0)), params["layers"])
     x = L.rms_norm(x, params["final_norm"])
     logits = L.unembed(cfg, params["embed"], x)
-    new_cache = {"k": kvs["k"], "v": kvs["v"], "pos": pos + 1}
-    return logits, new_cache
+    return logits, {"k": ks, "v": vs, "pos": pos + 1}

@@ -137,3 +137,44 @@ def test_decode_step_never_repeats_the_cache():
     repeated = [(n, s) for n, s in shapes
                 if cache_len in s and cfg.n_heads in s]
     assert not repeated, repeated
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_decode_step_writes_one_row_a_layer_in_place():
+    """A vmapped dense decode step carries the stacked cache through its
+    layer loop: no scan takes or returns an array that spans the cache
+    length as xs or ys (a per-layer slice and restack), and every write
+    into the cache is one position per lane per layer."""
+    cache_len, n_lanes = 24, 3
+    cfg = _cfg(4, 0, "bfloat16", n_layers=2)
+    arch = Arch(cfg)
+    params = jax.eval_shape(arch.init, jax.random.PRNGKey(0))
+    slab = arch.init_lane_cache(n_lanes, cache_len, abstract=True)
+    tokens = jax.ShapeDtypeStruct((n_lanes, 1, 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        jax.vmap(arch.decode_step, in_axes=(None, 0, 0)))(params, slab, tokens)
+    eqns = list(_eqns(jaxpr.jaxpr))
+
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    assert scans
+    for e in scans:
+        n_in = e.params["num_consts"] + e.params["num_carry"]
+        xs_ys = e.invars[n_in:] + e.outvars[e.params["num_carry"]:]
+        spans = [v.aval.shape for v in xs_ys if cache_len in v.aval.shape]
+        assert not spans, spans
+
+    row = n_lanes * cfg.n_kv_heads * cfg.resolved_head_dim
+    writes = [e for e in eqns
+              if e.primitive.name in ("dynamic_update_slice", "scatter")
+              and cache_len in e.invars[0].aval.shape]
+    assert len(writes) == 2  # K and V, once in the layer loop's body
+    for e in writes:
+        update = e.invars[1 if e.primitive.name == "dynamic_update_slice"
+                          else 2]
+        assert update.aval.size == row, (e.primitive.name, update.aval)

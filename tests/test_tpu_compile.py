@@ -1,20 +1,29 @@
 """The probe kernel compiles for a TPU v5e, where the train and serve paths
 call it: bf16 and f32 activations at real widths, per-lane logits under
 ``jax.vmap``, the entropy variant, and a monitored probe on a data mesh.
+The dense serve megastep compiles for one v5e with its KV slab in place.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  The topology is described inside a fixture (never at import), so
 every test worker collects the same tests and only the one running this
 file loads the TPU library.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro import core as scalpel
+from repro.core.context import MonitorSpec
 from repro.dist.partition import sharding_ctx
 from repro.kernels import ops
 from repro.launch.mesh import auto_mesh
+from repro.models.registry import Arch
+from repro.models.spec import ModelConfig
+from repro.serve.driver import DecodeDriver
 
 
 @pytest.fixture(scope="module")
@@ -106,3 +115,80 @@ def test_probe_kernel_runs_per_shard_under_a_sharded_jit(topo, monkeypatch):
                            out_specs=PartitionSpec("data")), state, x)
     assert "all-reduce" in spmd and "tpu_custom_call" not in spmd
     assert "tpu_custom_call" in per_shard
+
+
+def _materialized(hlo: str):
+    """(name, op, element count, fused root op) of every instruction that
+    owns a buffer: those outside the computations that fusions call."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    roots, comp = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            comp = m.group(1)
+        m = re.match(r"\s*ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if m and comp in fused:
+            roots[comp] = m.group(1)
+    comp = None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if comp in fused or not m:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        n = math.prod(int(d) for d in m.group(2).split(",") if d)
+        yield (m.group(1), m.group(3), n,
+               roots.get(called.group(1)) if called else None)
+
+
+def test_dense_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
+    """The serve megastep of a dense GQA model (kv 8, head_dim 128, 2
+    layers, 4 lanes, cache 1024, K = 2, slab donated) moves no slab on the
+    chip: no layout copy of the slab or of one layer's slab, no second
+    slab allocated, no layer sliced out into a buffer of its own.  What
+    owns a buffer of a layer's size or more is the donated slab and its
+    in-place row writes."""
+    cfg = ModelConfig(name="slab", family="dense", n_layers=2, d_model=512,
+                      n_heads=16, n_kv_heads=8, d_ff=1024, vocab=1024,
+                      head_dim=128, qk_norm=True, param_dtype="bfloat16",
+                      compute_dtype="bfloat16")
+    lanes, cache_len, k_steps = 4, 1024, 2
+    arch = Arch(cfg)
+    spec = MonitorSpec.of([])
+    mon = scalpel.Monitor(spec,
+                          telemetry=scalpel.ScalpelRuntime(spec).telemetry)
+    driver = DecodeDriver(arch, mon, cache_len=cache_len, temperature=0.0,
+                          steps_per_commit=k_steps)
+    ls = jax.eval_shape(lambda: mon.lane_init(lanes))
+    ring = jax.eval_shape(
+        lambda: mon.telemetry.make_token_ring(lanes, 2 * k_steps))
+    lane_i32 = jax.ShapeDtypeStruct((lanes,), jnp.int32)
+    args = (ls.lane_calls, ls.lane_values, ls.lane_samples, ls.lane_sched,
+            ls.calls, ls.values, ls.samples, ls.step, ls.ring, ls.params,
+            ls.tparams, jax.eval_shape(arch.init, jax.random.PRNGKey(0)),
+            arch.init_lane_cache(lanes, cache_len, abstract=True),
+            jax.ShapeDtypeStruct((lanes, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((lanes, 2), jnp.uint32), lane_i32, lane_i32,
+            ring)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    hlo = driver._megastep.lower(*args).compile().as_text()
+
+    layer = lanes * cfg.n_kv_heads * cache_len * cfg.resolved_head_dim
+    big = [(name, root or op) for name, op, n, root in _materialized(hlo)
+           if n >= layer]
+    assert any(op == "dynamic-update-slice" for _, op in big)
+    moved = [b for b in big if b[1] not in
+             ("parameter", "get-tuple-element", "bitcast",
+              "dynamic-update-slice")]
+    assert not moved, moved
+    copies = [line.strip()[:120] for line in hlo.splitlines()
+              if re.search(r"= \w+\[[\d,]*\]\S* copy(-start)?\(", line)
+              and math.prod(int(d) for d in re.search(
+                  r"\[([\d,]*)\]", line).group(1).split(",") if d) >= layer]
+    assert not copies, copies
