@@ -1,9 +1,7 @@
 """Assigned-architecture configs (--arch <id>).
 
-Each module defines CONFIG (exact assigned hyperparameters), SMOKE (reduced
-same-family config for CPU tests) and CELLS (per-shape execution policy:
-microbatches, optimizer tier — chosen to fit the 16 GB/chip v5e budget; see
-EXPERIMENTS.md §Dry-run for the measured bytes).
+Each module defines CONFIG (exact assigned hyperparameters) and SMOKE
+(reduced same-family config for CPU tests).
 """
 from __future__ import annotations
 
@@ -44,10 +42,3 @@ def model_config(arch_id: str, smoke: bool = False, **overrides):
     cfg = mod.SMOKE if smoke else mod.CONFIG
     return cfg.replace(**overrides) if overrides else cfg
 
-
-def cell_policy(arch_id: str, shape_name: str) -> dict:
-    mod = load(arch_id)
-    cells = getattr(mod, "CELLS", {})
-    out = dict(cells.get("default", {}))
-    out.update(cells.get(shape_name, {}))
-    return out

@@ -1,12 +1,6 @@
 """arctic-480b [moe]: 35L d_model=7168 56H (GQA kv=8) d_ff=4864
 vocab=32000, MoE 128 experts top-2 + dense residual
 [hf:Snowflake/snowflake-arctic-base; hf].
-
-Memory plan (16 GB/chip): fp32 params updated in place (no separate
-master), Adafactor-style factored second moment, no momentum,
-microbatches=1 (no fp32 accumulation buffer), per-leaf f32 grad casts;
-experts 8/chip under 16-way expert parallelism.  Measured bytes in
-EXPERIMENTS.md §Dry-run.
 """
 from repro.models import ModelConfig, MoEConfig
 
@@ -24,11 +18,3 @@ SMOKE = CONFIG.replace(
     remat="none",
     moe=MoEConfig(n_experts=8, top_k=2, dense_residual=True, dense_ff=96),
 )
-
-CELLS = {
-    "default": {"opt_state": "factored", "opt_momentum": False,
-                "opt_master": False},
-    "train_4k": {"microbatches": 1,
-                 "model_overrides": {"param_dtype": "float32"}},
-    "prefill_32k": {"microbatches": 1},
-}

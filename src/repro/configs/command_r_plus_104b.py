@@ -16,9 +16,3 @@ SMOKE = CONFIG.replace(
     n_kv_heads=2, d_ff=256, vocab=512, param_dtype="float32",
     compute_dtype="float32", remat="none",
 )
-
-CELLS = {
-    "default": {"opt_state": "int8"},
-    "train_4k": {"microbatches": 8},
-    "prefill_32k": {"microbatches": 1},
-}

@@ -16,8 +16,3 @@ SMOKE = CONFIG.replace(
     d_ff=128, vocab=512, param_dtype="float32", compute_dtype="float32",
     remat="none", moe=MoEConfig(n_experts=4, top_k=2),
 )
-
-CELLS = {
-    "default": {"opt_state": "int8"},
-    "train_4k": {"microbatches": 8},
-}

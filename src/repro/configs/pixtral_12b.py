@@ -18,8 +18,3 @@ SMOKE = CONFIG.replace(
     d_ff=160, vocab=512, head_dim=16,
     param_dtype="float32", compute_dtype="float32", remat="none",
 )
-
-CELLS = {
-    "default": {"opt_state": "f32"},
-    "train_4k": {"microbatches": 2},
-}

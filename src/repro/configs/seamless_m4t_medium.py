@@ -20,8 +20,3 @@ SMOKE = CONFIG.replace(
     n_heads=4, n_kv_heads=4, d_ff=128, vocab=512,
     param_dtype="float32", compute_dtype="float32", remat="none",
 )
-
-CELLS = {
-    "default": {"opt_state": "f32"},
-    "train_4k": {"microbatches": 1},
-}

@@ -17,8 +17,3 @@ SMOKE = CONFIG.replace(
     n_kv_heads=4, vocab=512, param_dtype="float32",
     compute_dtype="float32", remat="none", ssm=SSMConfig(chunk=16),
 )
-
-CELLS = {
-    "default": {"opt_state": "f32"},
-    "train_4k": {"microbatches": 1},
-}

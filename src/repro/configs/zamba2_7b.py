@@ -29,8 +29,3 @@ SMOKE = CONFIG.replace(
     hybrid=HybridConfig(hybrid_layer_ids=(2, 3, 5), num_mem_blocks=2,
                         adapter_rank=8),
 )
-
-CELLS = {
-    "default": {"opt_state": "f32"},
-    "train_4k": {"microbatches": 2},
-}

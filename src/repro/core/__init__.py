@@ -1,6 +1,6 @@
 """ScALPEL-JAX core: the paper's contribution as a composable JAX module.
 
-Public API (see DESIGN.md §2 for the paper mapping):
+Public API:
 
     spec    = scalpel.MonitorSpec / spec_from_mapping / spec_from_discovery
     mon     = scalpel.Monitor(spec, params, telemetry=...)
@@ -12,9 +12,7 @@ Public API (see DESIGN.md §2 for the paper mapping):
 
     runtime = scalpel.ScalpelRuntime(spec, config_path=..., install_signal=True)
 
-The legacy hand-threaded region API (``collecting`` + ``state.add(col.delta)``)
-is DEPRECATED — it survives as a shim over ``Monitor.open``; see the README
-migration table.
+``Monitor.open`` is the raw collection region ``wrap`` is built on.
 """
 from .adaptive import (  # noqa: F401
     AdaptiveConfig,
@@ -45,9 +43,7 @@ from .events import (  # noqa: F401
     registered,
 )
 from .instrument import (  # noqa: F401
-    breakpoint_mode,
     capture,
-    collecting,
     current_collector,
     discover,
     discovering,

@@ -3,7 +3,7 @@
 The paper reads MSR-backed counters (DTLB_MISSES, L2_LINES_IN, RESOURCE_STALLS
 ...) through libpfm.  On a TPU there is no user-readable MSR file, but the
 *causes* the paper is after are visible to the compiler (FLOPs / bytes /
-collective traffic — see backends/xla_cost.py) and to the program itself:
+collective traffic in the compiled HLO) and to the program itself:
 statistics of the live tensors flowing through each scope.  This module is the
 registry of those in-graph events.
 
@@ -32,8 +32,8 @@ functions (MOE_LOAD, SSM_STATE_RMS, ...) keep their bespoke ``fn`` path.
 Every event also keeps a direct (unfused) implementation ``fn: (tensor |
 tensors-dict) -> f32 scalar`` — the numerical reference the planned path is
 checked against (allclose: accumulation order differs between the fused
-single pass and independent reductions — benchmarks/overhead.py,
-tests/test_probe_reduce, tests/test_plan).
+single pass and independent reductions — tests/test_probe_reduce,
+tests/test_plan).
 
 Events are tagged EXTENSIVE (accumulates by summation across calls: counts,
 bytes, flops) or INTENSIVE (accumulates as a mean across monitored calls:

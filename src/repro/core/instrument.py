@@ -8,9 +8,8 @@ multiplex schedule is decided by the MonitorSpec/MonitorParams, not the model.
 
 Execution model
 ---------------
-* ``monitor.Monitor.wrap`` (the public API) — or the DEPRECATED
-  ``collecting(spec, params, state)`` shim — opens a root Collector for a
-  step.
+* ``monitor.Monitor`` opens a root Collector for a step (``Monitor.wrap``,
+  or the raw region ``Monitor.open``).
 * ``function(name)`` pushes a scope; entering a scope that is in the
   compile-time set increments its call counter *in-graph* (interception).
 * ``probe(**tensors)`` evaluates the current scope's context: a ``lax.cond``
@@ -97,20 +96,19 @@ class Collector:
     trace-time Python lists and materialized as ONE scatter-add per scope
     when the region finalizes (``delta``).  A scope probed k times per step
     costs k event computations but only one dynamic-update-slice — without
-    this, the per-call scatters dominated the monitoring overhead
-    (EXPERIMENTS.md §Perf, instrumentation iteration 1).
+    this, the per-call scatters dominated the monitoring overhead.
 
     Event evaluation is PLAN-DRIVEN: every (scope, event set) pair executes
     its compiled ``plan.MomentPlan`` — the exact channel sweep per probed
     tensor that set's slots finalize from, plus the set's bespoke slots,
     landing through one batched scatter over the set's live-slot footprint.
     ``plan_mode="union"`` widens each set's sweeps to the cross-set union
-    (the pre-plan behaviour) — the benchmark baseline, not a hot path.
+    (the pre-plan behaviour) — the reference the tests compare per-set
+    plans against, not a hot path.
     """
 
     def __init__(self, spec: MonitorSpec, params: MonitorParams,
-                 calls_base, backends: tuple = (),
-                 plan_mode: str = "per_set"):
+                 calls_base, plan_mode: str = "per_set"):
         if plan_mode not in ("per_set", "union"):
             raise ValueError(f"unknown plan_mode {plan_mode!r}")
         self.spec = spec
@@ -121,7 +119,6 @@ class Collector:
         self.calls_base = calls_base
         self.scope_path: list[str] = []
         self._extended: list[bool] = []
-        self.backends = backends
         self.plan_mode = plan_mode
         # deferred accumulators (trace-time); _vals/_smps hold per-scope
         # vectors of the SCOPE's width (dense plan layout), not max_slots
@@ -398,43 +395,6 @@ class DiscoveryCollector:
 # --------------------------------------------------------------------------
 
 @contextlib.contextmanager
-def collecting(spec: MonitorSpec, params: MonitorParams,
-               state: CounterState | None = None, *,
-               plan_mode: str = "per_set"):
-    """DEPRECATED: open a root collection region; yields the Collector.
-
-    This is the legacy hand-threaded API — every call site must fold
-    ``col.delta`` into its own carried CounterState.  New code should use
-    the functional ``scalpel.Monitor`` transformation (core/monitor.py):
-    ``mon.wrap(step_fn)`` threads one MonitorState pytree (compact
-    counters, telemetry ring, step stamp, params) automatically and
-    cross-device-reduces over the mesh.  ``collecting`` survives as a thin
-    shim over ``Monitor.open`` for existing call sites and as the manual
-    baseline the overhead benchmark measures ``Monitor.wrap`` against; see
-    the migration table in README.md.
-
-    ``state`` supplies the call-count base so multiplex schedules continue
-    across steps.  ``plan_mode="union"`` compiles every event set against
-    the cross-set channel union (the pre-plan probe behaviour) — the
-    benchmark baseline, not a hot path.
-    """
-    import warnings
-
-    from . import monitor as monitor_lib
-
-    warnings.warn(
-        "scalpel.collecting() is deprecated; use scalpel.Monitor(spec).wrap"
-        "(step_fn) (or @scalpel.monitored) — see the README migration table",
-        DeprecationWarning, stacklevel=3,
-    )
-    mon = monitor_lib.Monitor(spec, params=params, counter_axes=(),
-                              plan_mode=plan_mode)
-    with mon.open(params, calls_base=state.calls if state is not None
-                  else None) as col:
-        yield col
-
-
-@contextlib.contextmanager
 def discovering():
     col = DiscoveryCollector()
     _stack().append(col)
@@ -445,53 +405,16 @@ def discovering():
 
 
 @contextlib.contextmanager
-def breakpoint_mode(monitor=None, scopes=None):
-    """'Perfmon mode': every scope entry/exit fires a host round-trip.
-
-    Deliberately reproduces the ptrace/breakpoint technique the paper
-    measures against (perfmon was 2-3 orders of magnitude slower than
-    compiler-directed callbacks).  Must be active while the step is TRACED
-    so the ``io_callback``s are planted in the graph.  ``scopes``: restrict
-    breakpoints to the named scopes (None = all).
-    """
-    from .backends import host_callback as hc
-
-    prev = getattr(_TLS, "bp", None)
-    _TLS.bp = (monitor or hc.global_monitor(),
-               frozenset(scopes) if scopes else None)
-    try:
-        yield _TLS.bp[0]
-    finally:
-        _TLS.bp = prev
-
-
-def _fire_breakpoint(name: str, edge: str) -> None:
-    bp = getattr(_TLS, "bp", None)
-    if bp is None:
-        return
-    monitor, only = bp
-    if only is not None and name not in only:
-        return
-    from .backends import host_callback as hc
-
-    hc.breakpoint_probe(f"{name}@{edge}", 0.0, monitor)
-
-
-@contextlib.contextmanager
 def function(name: str):
     """Scope context manager — the entry/exit callback pair (paper C1).
 
     Entering counts one interception of the full scope path.  Also opens a
-    ``jax.named_scope`` so the scope name lands in HLO op metadata, which the
-    xla_cost backend uses for per-scope static cost attribution.
+    ``jax.named_scope`` so the scope name lands in the HLO op metadata of
+    the scope's ops, where a device trace reads it.
     """
-    _fire_breakpoint(name, "entry")
     col = current_collector()
     if col is None:
-        try:
-            yield None
-        finally:
-            _fire_breakpoint(name, "exit")
+        yield None
         return
     scope = col.push(name)
     try:
@@ -500,7 +423,6 @@ def function(name: str):
             yield scope
     finally:
         col.pop()
-        _fire_breakpoint(name, "exit")
 
 
 def probe(**tensors) -> None:

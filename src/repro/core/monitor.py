@@ -1,9 +1,9 @@
 """Functional ``Monitor`` transformation — one pytree, compact end-to-end.
 
 The paper's promise is *transparent* monitoring: no source modifications
-beyond naming scopes.  The legacy ``collecting()`` API kept a seam open —
-every call site had to hand-thread counters (``state = state.add(col.delta)``)
-and nobody aggregated them across devices.  This module closes both:
+beyond naming scopes.  A bare collection region leaves a seam open — every
+call site has to hand-thread counters (``state = state.add(col.delta)``) and
+nobody aggregates them across devices.  This module closes both:
 
 * ``mon = Monitor(spec, params, telemetry=...)`` and ``step = mon.wrap(fn)``
   (or ``@monitored(spec)``) turn an ordinary step function into a pure
@@ -259,15 +259,16 @@ class Monitor:
             tparams=mstate.tparams if tparams is None else tparams,
         )
 
-    # -- the raw collection region (what collecting() shims onto) ---------
+    # -- the raw collection region ----------------------------------------
     @contextlib.contextmanager
     def open(self, params: MonitorParams | None = None, calls_base=None):
         """Open a collection region; yields the Collector.
 
-        The low-level primitive ``wrap`` is built on (and the deprecated
-        ``collecting()`` shims onto): callers that need custom threading —
-        e.g. collection inside a ``value_and_grad`` aux — use this and fold
-        ``col.compact_delta()`` through ``commit`` themselves.
+        The low-level primitive ``wrap`` is built on: callers that need
+        custom threading — e.g. collection inside a ``value_and_grad`` aux —
+        use this and fold ``col.compact_delta()`` through ``commit``
+        themselves.  ``calls_base`` (the calls of earlier regions; zeros by
+        default) continues the multiplex schedule across regions.
         """
         params = params if params is not None else self.params
         base = calls_base if calls_base is not None else jnp.zeros(
@@ -640,9 +641,9 @@ class Monitor:
         stamp, ring.  Donation semantics as in ``jit``.
 
         The returned callable exposes the underlying ``jax.jit`` object as
-        ``._cjit`` (for cache-stats/no-retrace assertions: the donation
-        checks the benchmarks record), and ``.lower(mstate, *args)`` — the
-        jit's ``lower`` with the wrapped signature, for HLO inspection.
+        ``._cjit`` (for cache-stats/no-retrace assertions), and
+        ``.lower(mstate, *args)`` — the jit's ``lower`` with the wrapped
+        signature, for HLO inspection.
         """
 
         def core(calls, values, samples, sched_calls, step, ring, params,

@@ -31,8 +31,9 @@ context objects):
   inputs) demonstrably leave it, and the traced graph, untouched.
 
 ``union=True`` compiles the pre-plan behaviour (every set sweeps the union
-of channels across all sets) — kept as the benchmark baseline
-(benchmarks/overhead.py ``run_plan_sweep``), not a supported hot path.
+of channels across all sets) — kept as the reference the tests compare
+per-set plans against (``tests/test_probe_reduce.py``), not a supported hot
+path.
 """
 from __future__ import annotations
 
@@ -176,7 +177,7 @@ def compile_scope_plans(
     concrete probe call exists.
 
     ``union=True`` widens every set's sweeps to the union of channels over
-    ALL sets (the pre-plan behaviour, kept as a benchmark baseline).
+    ALL sets (the pre-plan behaviour, kept as the tests' reference).
     """
     def live(i) -> bool:
         if avail is None:

@@ -1,9 +1,7 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state.  The dry-run (launch/dryrun.py) sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; everything else sees the real device count.
+never touches jax device state.
 
 Every mesh is built with Auto axis types: the model code places tensors
 with ``with_sharding_constraint`` (``dist/partition.shard``) and leaves the
@@ -11,9 +9,8 @@ rest to the compiler, which Explicit axes — ``jax.make_mesh``'s default in
 the installed JAX — refuse.
 
 Mesh axes:
-  data  — batch / FSDP axis (16-way per pod)
-  model — TP / vocab / expert axis (16-way, maps to the high-bandwidth ring)
-  pod   — pod super-axis (pure DP across pods; gradient all-reduce crosses it)
+  data  — batch / FSDP axis
+  model — TP / vocab / expert axis (maps to the high-bandwidth ring)
 """
 from __future__ import annotations
 
@@ -33,22 +30,9 @@ def auto_mesh(shape, axes, devices=None):
                              axis_types=types)
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return auto_mesh(shape, axes)
-
-
 def make_host_mesh(data: int | None = None, model: int = 1):
     """Small mesh over whatever local devices exist (tests/examples)."""
     n = len(jax.devices())
     if data is None:
         data = max(1, n // model)
     return auto_mesh((data, model), ("data", "model"))
-
-
-def describe(mesh) -> str:
-    return (
-        f"mesh {dict(mesh.shape)} on {mesh.devices.size} devices "
-        f"({mesh.devices.flat[0].platform})"
-    )

@@ -1,6 +1,6 @@
 """Test-support harnesses (fault injection, failing sinks).
 
-Importable from production examples/benchmarks too — everything here is
+Importable from examples and simulated hosts too — everything here is
 deterministic and dependency-free; nothing imports pytest.
 """
 from .faults import (  # noqa: F401

@@ -1,5 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests see 1 CPU device;
-only launch/dryrun.py (its own process) forces 512 placeholder devices."""
+the multi-device tests force host devices in subprocesses of their own."""
 import importlib.util
 import os
 import sys

@@ -26,7 +26,8 @@ def _spec_one(scope="f", sets=None, period=1):
 
 
 def run_step(spec, params, state, fn, *args):
-    with scalpel.collecting(spec, params, state) as col:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state.calls) as col:
         out = fn(*args)
     return out, state.add(col.delta)
 
@@ -82,7 +83,8 @@ def test_mask_change_does_not_retrace():
     @jax.jit
     def step(state, params, x):
         traces.append(1)
-        with scalpel.collecting(spec, params, state) as col:
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=state.calls) as col:
             with scalpel.function("f"):
                 scalpel.probe(x=x)
         return state.add(col.delta)
@@ -171,7 +173,8 @@ def test_multiplex_continues_across_steps():
 
     @jax.jit
     def step(state, x):
-        with scalpel.collecting(spec, params, state) as col:
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=state.calls) as col:
             with scalpel.function("f"):
                 scalpel.probe(x=x)
         return state.add(col.delta)
@@ -220,13 +223,15 @@ def test_scan_with_counters_matches_unrolled():
 
     # scan version
     state = CounterState.zeros(spec)
-    with scalpel.collecting(spec, params, state) as col:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state.calls) as col:
         scalpel.scan_with_counters(body, jnp.zeros(()), xs)
     scan_state = state.add(col.delta)
 
     # unrolled version
     state2 = CounterState.zeros(spec)
-    with scalpel.collecting(spec, params, state2) as col2:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state2.calls) as col2:
         c = jnp.zeros(())
         for i in range(6):
             c, _ = body(c, xs[i])
@@ -258,7 +263,8 @@ def test_scan_with_counters_remat():
 
     def loss(c0):
         state = CounterState.zeros(spec)
-        with scalpel.collecting(spec, params, state) as col:
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=state.calls) as col:
             c, _ = scalpel.scan_with_counters(
                 body, c0, xs, remat=jax.checkpoint
             )

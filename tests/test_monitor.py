@@ -1,5 +1,5 @@
 """Functional Monitor transformation: one MonitorState pytree, compact
-counters end-to-end, plan dedup, checkpoint attestation, deprecation shim."""
+counters end-to-end, plan dedup, checkpoint attestation, the raw region."""
 import warnings
 
 import jax
@@ -38,19 +38,19 @@ def _work(x):
 
 
 def _manual_state(spec, params, x, steps=1):
-    """The deprecated hand-threaded baseline (shim keeps it working)."""
+    """The hand-threaded baseline: raw ``Monitor.open`` regions whose
+    padded deltas the caller folds itself."""
     s = CounterState.zeros(spec)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
 
-        @jax.jit
-        def step(s, params, x):
-            with scalpel.collecting(spec, params, s) as col:
-                _work(x)
-            return s.add(col.delta)
+    @jax.jit
+    def step(s, params, x):
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=s.calls) as col:
+            _work(x)
+        return s.add(col.delta)
 
-        for _ in range(steps):
-            s = step(s, params, x)
+    for _ in range(steps):
+        s = step(s, params, x)
     return s
 
 
@@ -469,31 +469,41 @@ def test_exit_report_still_prints_without_close(capsys):
 
 
 # ---------------------------------------------------------------------------
-# the deprecation shim
+# the raw region
 # ---------------------------------------------------------------------------
 
-def test_collecting_shim_warns_and_still_works():
+@pytest.mark.parametrize("plan_mode", ["per_set", "union"])
+def test_open_continues_schedule_from_calls_base(plan_mode):
+    """A region opened with ``calls_base`` at an earlier region's calls
+    continues its multiplex schedule: two regions that probe once each
+    count what one region that probes twice counts."""
     spec = _spec()
     params = MonitorParams.all_on(spec)
-    state = CounterState.zeros(spec)
-    with pytest.warns(DeprecationWarning, match="Monitor"):
-        with scalpel.collecting(spec, params, state) as col:
-            with scalpel.function("cold"):
-                scalpel.probe(x=jnp.ones(3))
-        state = state.add(col.delta)
-    assert int(state.calls[spec.scope_index("cold")]) == 1
+    mon = scalpel.Monitor(spec, params=params, counter_axes=(),
+                          plan_mode=plan_mode)
+    x = jnp.arange(1.0, 5.0)
 
+    def probe_hot():
+        with scalpel.function("hot"):
+            scalpel.probe(x=x)
 
-def test_gated_trees_free_of_deprecated_calls():
-    """The CI grep-gate, run in-process: src/ and examples/ must not call
-    collecting() outside the shim's own definition."""
-    import pathlib
-    import sys
+    with mon.open(params, calls_base=None) as col:
+        probe_hot()
+    first = col.delta
+    with mon.open(params, calls_base=first.calls) as col:
+        probe_hot()
+    second = col.delta
+    with mon.open(params) as col:
+        probe_hot()
+        probe_hot()
+    whole = col.delta
 
-    root = pathlib.Path(__file__).resolve().parent.parent
-    sys.path.insert(0, str(root / "tools"))
-    try:
-        import check_deprecated
-        assert check_deprecated.violations(root) == []
-    finally:
-        sys.path.pop(0)
+    hot = spec.scope_index("hot")
+    # period 1, two sets: the first region samples set 0 (MEAN, slot 0),
+    # the second set 1 (L2NORM, slot 1)
+    assert np.asarray(first.samples)[hot, :2].tolist() == [1, 0]
+    assert np.asarray(second.samples)[hot, :2].tolist() == [0, 1]
+    split = first.add(second)
+    np.testing.assert_array_equal(split.calls, whole.calls)
+    np.testing.assert_array_equal(split.samples, whole.samples)
+    np.testing.assert_allclose(split.values, whole.values, rtol=1e-6)

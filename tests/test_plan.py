@@ -134,12 +134,14 @@ def test_scan_carries_compact_footprint_and_matches_unrolled():
         return c + 1.0, x
 
     state = CounterState.zeros(spec)
-    with scalpel.collecting(spec, params, state) as col:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state.calls) as col:
         scalpel.scan_with_counters(body, jnp.zeros(()), xs)
     scanned = state.add(col.delta)
 
     state2 = CounterState.zeros(spec)
-    with scalpel.collecting(spec, params, state2) as col2:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state2.calls) as col2:
         c = jnp.zeros(())
         for i in range(8):
             c, _ = body(c, xs[i])
@@ -241,7 +243,8 @@ def test_config_hot_swap_switches_plans_without_retrace(tmp_path):
 
     def step(state, mparams, x):
         traces.append(1)
-        with scalpel.collecting(spec, mparams, state) as col:
+        with scalpel.Monitor(spec, params=mparams, counter_axes=()).open(
+                mparams, calls_base=state.calls) as col:
             with scalpel.function("hot"):
                 scalpel.probe(x=x)
             with scalpel.function("cold"):
@@ -289,8 +292,9 @@ def test_plan_mode_inherited_by_scan_children():
     outs = {}
     for mode in ("per_set", "union"):
         state = CounterState.zeros(spec)
-        with scalpel.collecting(spec, params, state,
-                                plan_mode=mode) as col:
+        mon = scalpel.Monitor(spec, params=params, counter_axes=(),
+                              plan_mode=mode)
+        with mon.open(params, calls_base=state.calls) as col:
             assert col.plan_mode == mode
             scalpel.scan_with_counters(body, jnp.zeros(()), xs)
         outs[mode] = state.add(col.delta)

@@ -1,6 +1,6 @@
 """Fused probe reductions: Pallas moment kernel (incl. the optional entropy
 channel) vs jnp reference, and per-set-planned vs union-planned event
-evaluation through a real collecting() region."""
+evaluation through a real ``Monitor.open`` region."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,12 +167,14 @@ def test_channels_for_is_per_group_not_per_registry():
 
 
 # ---------------------------------------------------------------------------
-# end to end: per-set plans vs the union baseline under collecting()
+# end to end: per-set plans vs the union reference under Monitor.open
 # ---------------------------------------------------------------------------
 
 def _run(spec, params, prog, *args, plan_mode):
     state = CounterState.zeros(spec)
-    with scalpel.collecting(spec, params, state, plan_mode=plan_mode) as col:
+    mon = scalpel.Monitor(spec, params=params, counter_axes=(),
+                          plan_mode=plan_mode)
+    with mon.open(params, calls_base=state.calls) as col:
         prog(*args)
     return state.add(col.delta)
 
@@ -242,7 +244,9 @@ def test_per_set_equals_union_under_jit_and_masks():
 
     def make(plan_mode):
         def step(x, s, mp):
-            with scalpel.collecting(spec, mp, s, plan_mode=plan_mode) as col:
+            mon = scalpel.Monitor(spec, params=mp, counter_axes=(),
+                                  plan_mode=plan_mode)
+            with mon.open(mp, calls_base=s.calls) as col:
                 with scalpel.function("hot"):
                     scalpel.probe(x=x)
                 with scalpel.function("cold"):
@@ -267,8 +271,9 @@ def test_unknown_plan_mode_rejected():
     spec = MonitorSpec.of(
         [ScopeContext.exhaustive("f", [EventSpec("MEAN", "x")])]
     )
+    params = MonitorParams.all_on(spec)
+    mon = scalpel.Monitor(spec, params=params, counter_axes=(),
+                          plan_mode="legacy")
     with pytest.raises(ValueError, match="plan_mode"):
-        with scalpel.collecting(spec, MonitorParams.all_on(spec),
-                                CounterState.zeros(spec),
-                                plan_mode="legacy"):
+        with mon.open(params, calls_base=CounterState.zeros(spec).calls):
             pass

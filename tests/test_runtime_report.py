@@ -30,7 +30,8 @@ def _spec():
 
 
 def _run(spec, params, state, values):
-    with scalpel.collecting(spec, params, state) as col:
+    with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+            params, calls_base=state.calls) as col:
         for v in values:
             with scalpel.function("f"):
                 scalpel.probe(x=jnp.full((4,), v))
@@ -144,7 +145,8 @@ def test_runtime_reload_swaps_masks_without_retrace(tmp_path):
     @jax.jit
     def step(state, params):
         traces.append(1)
-        with scalpel.collecting(spec, params, state) as col:
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=state.calls) as col:
             with scalpel.function("f"):
                 scalpel.probe(x=jnp.ones(3))
             with scalpel.function("g"):

@@ -40,7 +40,8 @@ def _bump(cs: CounterState, v: float = 1.0) -> CounterState:
 
 def _run_steps(spec, params, state, values):
     for v in values:
-        with scalpel.collecting(spec, params, state) as col:
+        with scalpel.Monitor(spec, params=params, counter_axes=()).open(
+                params, calls_base=state.calls) as col:
             with scalpel.function("f"):
                 scalpel.probe(x=jnp.full((4,), v))
             with scalpel.function("g"):
@@ -445,7 +446,8 @@ def test_runtime_reload_and_cadence_swap_never_retrace(tmp_path):
 
     def step(state, mparams, tparams, ring, step_no):
         traces.append(1)
-        with scalpel.collecting(spec, mparams, state) as col:
+        with scalpel.Monitor(spec, params=mparams, counter_axes=()).open(
+                mparams, calls_base=state.calls) as col:
             with scalpel.function("f"):
                 scalpel.probe(x=jnp.ones(3))
             with scalpel.function("g"):
