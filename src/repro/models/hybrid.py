@@ -22,10 +22,14 @@ The layers are kept as runs: run 0 from layer 0 up to the first site, each
 later run from a site up to the next.  A run's Mamba2 parameters (and, in
 serving, its SSD and conv states) are stacked and scanned; each block,
 site and site KV cache is its own leaf, so no program slices a weight or a
-cache out of a larger one.
+cache out of a larger one.  In decode a run's state stacks ride the layer
+loop's carry: layer ``i`` reads its state where it lies and writes the new
+one back in place (``_decode_run``).
 
-A site's KV cache is stored ``[batch, seq, kv_heads, head_dim]``, as the
-dense decoder's, and decode attends through ``layers.decode_attention``.
+A site's KV cache is stored ``[batch, kv_heads, head_dim, seq]``,
+sequence-minor, the order the chip lays the entry buffer out in (head_dim
+224 is not padded there); decode writes one column at ``pos`` and attends
+through ``layers.decode_attend`` in that order, so no step re-lays it out.
 
 Sub-quadratic backbone -> runs long_500k; the sites' KV caches are
 sequence-sharded in decode (``cache_axes``).
@@ -132,21 +136,38 @@ def _mamba_layer(cfg: ModelConfig, lp, x, t, state=None, length=None):
         return x + y, st
 
 
-def _run(cfg: ModelConfig, lp, x, t, states=None, length=None, remat=None):
-    """One run's stacked layers; ``t`` enters the first layer only."""
+def _run(cfg: ModelConfig, lp, x, t, length=None, remat=None):
+    """One run's stacked layers over a sequence; ``t`` enters the first
+    layer only.  Returns x and the run's stacked (ssm, conv) states."""
 
-    def body(carry, inp):
+    def body(carry, lpi):
         xx, tt = carry
-        if states is None:
-            xx, st = _mamba_layer(cfg, inp, xx, tt, length=length)
-        else:
-            lpi, s_ssm, s_conv = inp
-            xx, st = _mamba_layer(cfg, lpi, xx, tt, (s_ssm, s_conv))
+        xx, st = _mamba_layer(cfg, lpi, xx, tt, length=length)
         return (xx, jnp.zeros_like(tt)), st
 
-    xs = lp if states is None else (lp, *states)
-    (x, _), st = scalpel.scan_with_counters(body, (x, t), xs, remat=remat)
+    (x, _), st = scalpel.scan_with_counters(body, (x, t), lp, remat=remat)
     return x, st
+
+
+def _decode_run(cfg: ModelConfig, lp, x, t, s_ssm, s_conv):
+    """One run's stacked layers for one token.  The run's state stacks ride
+    the loop's carry, not its xs/ys: layer ``i`` reads its state where it
+    lies and writes the new one back at ``i``, so a step never restacks
+    them (under the serve driver's lane ``vmap`` ``i`` is unbatched, and
+    the write is in place)."""
+
+    def body(carry, lpi):
+        xx, tt, ss, cs, i = carry
+        st = (jax.lax.dynamic_index_in_dim(ss, i, keepdims=False),
+              jax.lax.dynamic_index_in_dim(cs, i, keepdims=False))
+        xx, (s2, c2) = _mamba_layer(cfg, lpi, xx, tt, st)
+        ss = jax.lax.dynamic_update_index_in_dim(ss, s2.astype(ss.dtype), i, 0)
+        cs = jax.lax.dynamic_update_index_in_dim(cs, c2.astype(cs.dtype), i, 0)
+        return (xx, jnp.zeros_like(tt), ss, cs, i + 1), None
+
+    (x, _, ss, cs, _), _ = scalpel.scan_with_counters(
+        body, (x, t, s_ssm, s_conv, jnp.int32(0)), lp)
+    return x, (ss, cs)
 
 
 def _site(cfg: ModelConfig, bp, sp, x, x0, attend):
@@ -231,7 +252,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
         return jax.ShapeDtypeStruct((n,) + sd.shape, sd.dtype)
 
     kv = jax.ShapeDtypeStruct(
-        (batch, cache_len, acfg.n_kv_heads, acfg.resolved_head_dim), kvd)
+        (batch, acfg.n_kv_heads, acfg.resolved_head_dim, cache_len), kvd)
     sites = cfg.hybrid.hybrid_layer_ids
     cache = {
         "mamba_ssm": [stack_n(m["ssm"], b - a) for a, b, _ in runs(cfg)],
@@ -250,7 +271,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
 
 def cache_axes(cfg: ModelConfig):
     n_runs, n_sites = len(runs(cfg)), len(cfg.hybrid.hybrid_layer_ids)
-    kv = ("batch", "kv_seq", None, None)
+    kv = ("batch", None, None, "kv_seq")
     return {
         "mamba_ssm": [("layers", "batch", "heads", None, None)] * n_runs,
         "mamba_conv": [("layers", "batch", None, None)] * n_runs,
@@ -261,11 +282,20 @@ def cache_axes(cfg: ModelConfig):
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens):
+    """One decode step.  Each site writes the token's K and V as one column
+    at ``pos`` of its ``[b, kv, hd, S]`` cache and attends in that order;
+    each run updates its state stacks in place (``_decode_run``)."""
     x = L.embed(cfg, params["embed"], tokens)
     # zamba's global skip uses the *current token's* embedding in decode
     x0 = x
     pos = cache["pos"]
     scale = attn_scale(cfg)
+
+    def write(c, new):  # new: [b, 1, kv, hd] -> column (:, :, :, pos)
+        col = jnp.transpose(new, (0, 2, 3, 1)).astype(c.dtype)
+        c = jax.lax.dynamic_update_slice(c, col, (0, 0, 0, pos))
+        return shard(c, "batch", None, None, "kv_seq")
+
     t = jnp.zeros_like(x)
     new = {"mamba_ssm": [], "mamba_conv": [], "site_k": [], "site_v": []}
     for (_, _, site), lp, s_ssm, s_conv in zip(
@@ -273,17 +303,20 @@ def decode_step(cfg: ModelConfig, params, cache, tokens):
             cache["mamba_conv"]):
         if site is not None:
             def attend(acfg, p, h, site=site):
-                y, kc, vc = L.decode_attention(
-                    acfg, p, h, cache["site_k"][site], cache["site_v"][site],
-                    pos, scale=scale)
-                return y, (kc, vc)
+                with scalpel.function("attn"):
+                    q, k_new, v_new = L.decode_qkv(acfg, p, h, pos)
+                    kc = write(cache["site_k"][site], k_new)
+                    vc = write(cache["site_v"][site], v_new)
+                    y = L.decode_attend(acfg, p, h, q, kc, vc, pos,
+                                        scale=scale, layout="bgdk")
+                    return y, (kc, vc)
 
             bp = params["blocks"][site % cfg.hybrid.num_mem_blocks]
             t, (kc, vc) = _site(cfg, bp, params["sites"][site], x, x0,
                                 attend)
             new["site_k"].append(kc)
             new["site_v"].append(vc)
-        x, (s2, c2) = _run(cfg, lp, x, t, states=(s_ssm, s_conv))
+        x, (s2, c2) = _decode_run(cfg, lp, x, t, s_ssm, s_conv)
         new["mamba_ssm"].append(s2)
         new["mamba_conv"].append(c2)
     x = L.rms_norm(x, params["final_norm"], NORM_EPS)
@@ -312,10 +345,10 @@ def prefill(cfg: ModelConfig, params, tokens, cache_len: int,
     logits = L.unembed(cfg, params["embed"], xl)
     kvd = jnp.dtype(cfg.compute_dtype)
 
-    def to_cache(a):  # [b, s, kv, hd] -> [b, cache_len, kv, hd]
-        a = jnp.pad(a.astype(kvd),
-                    ((0, 0), (0, cache_len - s), (0, 0), (0, 0)))
-        return shard(a, "batch", "kv_seq", None, None)
+    def to_cache(a):  # [b, s, kv, hd] -> [b, kv, hd, cache_len]
+        a = jnp.transpose(a.astype(kvd), (0, 2, 3, 1))
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, cache_len - s)))
+        return shard(a, "batch", None, None, "kv_seq")
 
     cache = {
         "mamba_ssm": [st[0] for st in states],

@@ -369,8 +369,8 @@ def decode_attend(cfg: ModelConfig, p, x, q, cache_k, cache_v, pos,
     """Score and mix one query token against a cache that already holds it.
 
     q: [b, 1, h, hd]; cache_{k,v} laid out as ``layout`` names it: "bkgd"
-    is [b, S, kv, hd], "bgkd" is [b, kv, S, hd]; slots past ``pos`` are
-    masked.  ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
+    is [b, S, kv, hd], "bgkd" is [b, kv, S, hd], "bgdk" is [b, kv, hd, S];
+    slots past ``pos`` are masked.  ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``).
     Returns y [b, 1, d] after the output projection.
 
     Grouped-query form: the query heads are grouped by the KV head they

@@ -1,7 +1,8 @@
 """The probe kernel compiles for a TPU v5e, where the train and serve paths
 call it: bf16 and f32 activations at real widths, per-lane logits under
 ``jax.vmap``, the entropy variant, and a monitored probe on a data mesh.
-The dense serve megastep compiles for one v5e with its KV slab in place.
+The dense and the hybrid serve megasteps compile for one v5e with their
+lane slabs in place.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology.  The topology is described inside a fixture (never at import), so
@@ -10,6 +11,7 @@ file loads the TPU library.
 """
 import math
 import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +23,9 @@ from repro.core.context import MonitorSpec
 from repro.dist.partition import sharding_ctx
 from repro.kernels import ops
 from repro.launch.mesh import auto_mesh
+from repro.models import hybrid
 from repro.models.registry import Arch
-from repro.models.spec import ModelConfig
+from repro.models.spec import HybridConfig, ModelConfig, SSMConfig
 from repro.serve.driver import DecodeDriver
 
 
@@ -117,46 +120,73 @@ def test_probe_kernel_runs_per_shard_under_a_sharded_jit(topo, monkeypatch):
     assert "tpu_custom_call" in per_shard
 
 
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Each computation's instruction lines, by name."""
+    comps, comp = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if m:
+            comp = comps.setdefault(m.group(1), [])
+        elif comp is not None:
+            comp.append(line)
+    return comps
+
+
+def _in_loops(hlo: str) -> set[str]:
+    """The computations that run inside a while loop: its bodies and all
+    they call."""
+    comps = _computations(hlo)
+    todo = re.findall(r"body=%([\w.\-]+)", hlo)
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += re.findall(r"%([\w.\-]+)", " ".join(
+                re.findall(r"(?:calls|body|condition|to_apply|"
+                           r"branch_computations)=(\{[^}]*\}|%[\w.\-]+)",
+                           "\n".join(comps.get(c, ())))))
+    return seen
+
+
+class Buffer(NamedTuple):
+    name: str
+    op: str            # the instruction's op, or a fusion's root op
+    n: int             # element count
+    comp: str          # the computation that holds it
+    on_chip: bool      # held in on-chip memory (a memory space ``S(n)``)
+
+
 def _materialized(hlo: str):
-    """(name, op, element count, fused root op) of every instruction that
-    owns a buffer: those outside the computations that fusions call."""
+    """Every instruction that owns an array buffer: those outside the
+    computations that fusions call."""
     fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
-    roots, comp = {}, None
-    for line in hlo.splitlines():
-        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
-        if m:
-            comp = m.group(1)
-        m = re.match(r"\s*ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
-        if m and comp in fused:
-            roots[comp] = m.group(1)
-    comp = None
-    for line in hlo.splitlines():
-        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
-        if m:
-            comp = m.group(1)
+    comps = _computations(hlo)
+    roots = {}
+    for comp in fused & comps.keys():
+        for line in comps[comp]:
+            m = re.match(r"\s*ROOT %[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+            if m:
+                roots[comp] = m.group(1)
+    for comp, lines in comps.items():
+        if comp in fused:
             continue
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
-                     r"([\w\-]+)\(", line)
-        if comp in fused or not m:
-            continue
-        called = re.search(r"calls=%([\w.\-]+)", line)
-        n = math.prod(int(d) for d in m.group(2).split(",") if d)
-        yield (m.group(1), m.group(3), n,
-               roots.get(called.group(1)) if called else None)
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]"
+                         r"(\S*) ([\w\-]+)\(", line)
+            if not m:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            op = roots.get(called.group(1)) if called else None
+            yield Buffer(m.group(1), op or m.group(4),
+                         math.prod(int(d) for d in m.group(2).split(",") if d),
+                         comp, bool(re.search(r"S\(\d+\)", m.group(3))))
 
 
-def test_dense_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
-    """The serve megastep of a dense GQA model (kv 8, head_dim 128, 2
-    layers, 4 lanes, cache 1024, K = 2, slab donated) moves no slab on the
-    chip: no layout copy of the slab or of one layer's slab, no second
-    slab allocated, no layer sliced out into a buffer of its own.  What
-    owns a buffer of a layer's size or more is the donated slab and its
-    in-place row writes."""
-    cfg = ModelConfig(name="slab", family="dense", n_layers=2, d_model=512,
-                      n_heads=16, n_kv_heads=8, d_ff=1024, vocab=1024,
-                      head_dim=128, qk_norm=True, param_dtype="bfloat16",
-                      compute_dtype="bfloat16")
-    lanes, cache_len, k_steps = 4, 1024, 2
+def _megastep_hlo(one_chip, cfg: ModelConfig, lanes: int, cache_len: int,
+                  k_steps: int) -> str:
+    """The serve driver's megastep for ``cfg``, compiled for one v5e with
+    its lane slab donated."""
     arch = Arch(cfg)
     spec = MonitorSpec.of([])
     mon = scalpel.Monitor(spec,
@@ -177,11 +207,25 @@ def test_dense_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         args)
-    hlo = driver._megastep.lower(*args).compile().as_text()
+    return driver._megastep.lower(*args).compile().as_text()
+
+
+def test_dense_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
+    """The serve megastep of a dense GQA model (kv 8, head_dim 128, 2
+    layers, 4 lanes, cache 1024, K = 2, slab donated) moves no slab on the
+    chip: no layout copy of the slab or of one layer's slab, no second
+    slab allocated, no layer sliced out into a buffer of its own.  What
+    owns a buffer of a layer's size or more is the donated slab and its
+    in-place row writes."""
+    cfg = ModelConfig(name="slab", family="dense", n_layers=2, d_model=512,
+                      n_heads=16, n_kv_heads=8, d_ff=1024, vocab=1024,
+                      head_dim=128, qk_norm=True, param_dtype="bfloat16",
+                      compute_dtype="bfloat16")
+    lanes, cache_len = 4, 1024
+    hlo = _megastep_hlo(one_chip, cfg, lanes, cache_len, k_steps=2)
 
     layer = lanes * cfg.n_kv_heads * cache_len * cfg.resolved_head_dim
-    big = [(name, root or op) for name, op, n, root in _materialized(hlo)
-           if n >= layer]
+    big = [(b.name, b.op) for b in _materialized(hlo) if b.n >= layer]
     assert any(op == "dynamic-update-slice" for _, op in big)
     moved = [b for b in big if b[1] not in
              ("parameter", "get-tuple-element", "bitcast",
@@ -192,3 +236,67 @@ def test_dense_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
               and math.prod(int(d) for d in re.search(
                   r"\[([\d,]*)\]", line).group(1).split(",") if d) >= layer]
     assert not copies, copies
+
+
+def _relayouts(hlo: str, elems: int):
+    """(computation, name, dtype) of every ``copy`` of ``elems`` elements
+    or more, and of every ``copy-start`` of that size that changes the
+    layout.  One that keeps it moves a buffer between memory spaces (the
+    compiler's prefetches and evictions), which a small model's slab fits
+    in."""
+    for comp, lines in _computations(hlo).items():
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?(\w+)\[([\d,]*)\]"
+                         r".*? (copy|copy-start)\(", line)
+            if not m or math.prod(
+                    int(d) for d in m.group(3).split(",") if d) < elems:
+                continue
+            if m.group(4) == "copy-start":
+                dst, src = (re.sub(r"S\(\d+\)", "", lay) for lay in
+                            re.findall(r"\w+\[[\d,]*\]\{([^}]*)\}", line)[:2])
+                if dst == src:
+                    continue
+            yield comp, m.group(1), m.group(2)
+
+
+def test_hybrid_megastep_keeps_the_slab_in_place_for_v5e(one_chip):
+    """The serve megastep of a Zamba2-shaped hybrid (d 512, 4 heads of 224,
+    Mamba2 heads of 64 with state 64 in 2 groups, 6 layers with sites 2
+    and 4, 4 lanes, cache 1024, K = 2, slab donated) updates its lane slab
+    where it lies: no layout copy of a site's K or V anywhere (their
+    buffers are the donated slab and its in-place column writes), and in
+    the K-step loop no copy or materialised broadcast of one layer's SSD
+    state for all lanes: each run's state stack rides the layer loop's
+    carry.  Outside the loop a run's state may be re-laid out once in and
+    once out."""
+    cfg = ModelConfig(name="hyb", family="hybrid", n_layers=6, d_model=512,
+                      n_heads=4, n_kv_heads=4, head_dim=224, d_ff=1024,
+                      vocab=1024,
+                      ssm=SSMConfig(d_state=64, d_conv=4, expand=2,
+                                    head_dim=64, n_groups=2),
+                      hybrid=HybridConfig(hybrid_layer_ids=(2, 4),
+                                          num_mem_blocks=2),
+                      param_dtype="bfloat16", compute_dtype="bfloat16")
+    lanes, cache_len = 4, 1024
+    hlo = _megastep_hlo(one_chip, cfg, lanes, cache_len, k_steps=2)
+
+    site = lanes * cfg.n_kv_heads * cfg.resolved_head_dim * cache_len
+    kv_copies = list(_relayouts(hlo, site))
+    assert not kv_copies, kv_copies
+    # in device memory (on-chip buffers are the compiler's prefetches)
+    owners = {b.op for b in _materialized(hlo) if b.n == site
+              and not b.on_chip} - {"get-tuple-element", "bitcast",
+                                     "copy-done"}
+    assert owners == {"parameter", "dynamic-update-slice"}, owners
+
+    n_heads = cfg.ssm.expand * cfg.d_model // cfg.ssm.head_dim
+    state = lanes * n_heads * cfg.ssm.head_dim * cfg.ssm.d_state
+    loops = _in_loops(hlo)
+    in_loop = [c for c in _relayouts(hlo, state)
+               if c[0] in loops and c[2] == "f32"]
+    in_loop += [(b.comp, b.name, b.op) for b in _materialized(hlo)
+                if b.comp in loops and b.n >= state and b.op == "broadcast"]
+    assert not in_loop, in_loop
+    outside = [c for c in _relayouts(hlo, state)
+               if c[0] not in loops and c[2] == "f32"]
+    assert len(outside) <= 2 * len(hybrid.runs(cfg)), outside

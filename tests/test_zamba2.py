@@ -132,6 +132,74 @@ def test_engine_lanes_at_different_positions_match_the_reference(
         assert gap.max() <= TOL, gap
 
 
+def _oracle_decode_step(cfg, params, cache, tokens):
+    """One decode step layer by layer: each layer's ``mamba2_decode`` on
+    its own state, each site's ``decode_attention`` on a ``[b, S, kv, hd]``
+    copy of its cache; states and caches come back in the stored order."""
+    from repro.models import layers as L
+
+    x = L.embed(cfg, params["embed"], tokens)
+    x0, pos, t = x, cache["pos"], jnp.zeros_like(x)
+    new = {"mamba_ssm": [], "mamba_conv": [], "site_k": [], "site_v": []}
+    for r, ((a, b, site), lp) in enumerate(zip(hybrid.runs(cfg),
+                                               params["mamba"])):
+        if site is not None:
+            kc, vc = (jnp.transpose(cache[k][site], (0, 3, 1, 2))
+                      for k in ("site_k", "site_v"))
+
+            def attend(acfg, p, h, kc=kc, vc=vc):
+                y, k2, v2 = L.decode_attention(
+                    acfg, p, h, kc, vc, pos, scale=hybrid.attn_scale(cfg))
+                return y, (k2, v2)
+
+            bp = params["blocks"][site % cfg.hybrid.num_mem_blocks]
+            t, kv = hybrid._site(cfg, bp, params["sites"][site], x, x0,
+                                 attend)
+            new["site_k"].append(jnp.transpose(kv[0], (0, 2, 3, 1)))
+            new["site_v"].append(jnp.transpose(kv[1], (0, 2, 3, 1)))
+        states = []
+        for i in range(b - a):
+            lpi = jax.tree.map(lambda w: w[i], lp)
+            h = L.rms_norm(x + t, lpi["ln"], hybrid.NORM_EPS)
+            y, st = ssm.mamba2_decode(cfg, lpi["mix"], h,
+                                      cache["mamba_ssm"][r][i],
+                                      cache["mamba_conv"][r][i])
+            x, t = x + y, jnp.zeros_like(t)
+            states.append(st)
+        new["mamba_ssm"].append(jnp.stack([st[0] for st in states]))
+        new["mamba_conv"].append(jnp.stack([st[1] for st in states]))
+    x = L.rms_norm(x, params["final_norm"], hybrid.NORM_EPS)
+    return L.unembed(cfg, params["embed"], x), dict(new, pos=pos + 1)
+
+
+def test_lane_vmapped_decode_step_matches_a_layer_by_layer_oracle(model):
+    """``decode_step`` under the serve driver's lane ``vmap``, three lanes
+    at different positions over seeded states and caches: the same logits
+    and the same updated states, K and V as the oracle's, lane by lane."""
+    arch, _, params = model
+    cfg, lanes, cache_len = arch.cfg, 3, 32
+    shapes = arch.init_lane_cache(lanes, cache_len, abstract=True)
+    leaves, tree = jax.tree.flatten(shapes)
+    rng = np.random.default_rng(6)
+    cache = jax.tree.unflatten(tree, [
+        jnp.asarray(rng.normal(size=sd.shape), sd.dtype) for sd in leaves])
+    cache["pos"] = jnp.asarray([5, 17, 30], jnp.int32)
+    tokens = jnp.asarray(_tokens(6, lanes))[:, None, None]
+    logits, got = jax.jit(jax.vmap(
+        lambda c, tok: hybrid.decode_step(cfg, params, c, tok)))(cache,
+                                                                 tokens)
+    for lane in range(lanes):
+        one = jax.tree.map(lambda a: a[lane], cache)
+        want_logits, want = _oracle_decode_step(cfg, params, one,
+                                                tokens[lane])
+        for a, b in zip(jax.tree.leaves((want_logits, want)),
+                        jax.tree.leaves(jax.tree.map(
+                            lambda a: a[lane], (logits, got)))):
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_allclose(b, a, rtol=1e-5,
+                                       atol=1e-5 * max(np.abs(a).max(), 1))
+
+
 def test_bucketed_prefill_equals_exact_length_prefill(model):
     arch, _, params = model
     toks = _tokens(3, 13)
@@ -147,8 +215,8 @@ def test_bucketed_prefill_equals_exact_length_prefill(model):
     for key in ("mamba_ssm", "mamba_conv", "site_k"):
         for a, b in zip(exact[key], bucket[key]):
             a, b = np.asarray(a), np.asarray(b)
-            if key == "site_k":  # [b, S, kv, hd]: the real positions
-                a, b = a[:, :13], b[:, :13]
+            if key == "site_k":  # [b, kv, hd, S]: the real positions
+                a, b = a[..., :13], b[..., :13]
             np.testing.assert_allclose(a, b, rtol=1e-4,
                                        atol=1e-5 * np.abs(a).max())
     np.testing.assert_allclose(np.asarray(le), np.asarray(lb), rtol=1e-4,
@@ -261,8 +329,11 @@ def test_site_kv_is_sequence_sharded_and_axes_mirror_the_cache(model):
     axes = arch.cache_axes()
     assert jax.tree.structure(cache) == jax.tree.structure(
         axes, is_leaf=lambda a: isinstance(a, tuple))
-    assert all(ax[1] == "kv_seq" for ax in axes["site_k"] + axes["site_v"])
-    assert cache["site_k"][0].shape[1] == 32
+    assert all(ax == ("batch", None, None, "kv_seq")
+               for ax in axes["site_k"] + axes["site_v"])
+    acfg = hybrid.attn_cfg(arch.cfg)
+    assert cache["site_k"][0].shape == (2, acfg.n_kv_heads,
+                                        acfg.resolved_head_dim, 32)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     shs = tree_shardings(cache, axes, mesh)
     assert len(jax.tree.leaves(shs)) == len(jax.tree.leaves(cache))
